@@ -48,6 +48,9 @@ def main() -> None:
                     help="tiny budgets, all sections + assertions, no JSON")
     ap.add_argument("--out", default="BENCH_amortized.json")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         args.scenarios, args.per_scenario = 3, 64
         args.epochs, args.batch_size = 4, 64

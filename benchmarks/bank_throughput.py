@@ -48,15 +48,16 @@ import argparse
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# sharded-section grids: scenario counts x virtual device counts (CPU via
-# --xla_force_host_platform_device_count, one worker subprocess per device
-# count so each gets its own XLA device topology)
+# sharded-section grids: scenario counts x device counts. The section runs
+# in this process over its own devices (a CPU run gets several from
+# XLA_FLAGS=--xla_force_host_platform_device_count=N); device counts above
+# what the process has are left out, and with fewer than 2 the section is
+# skipped
 SHARDED_FULL_S = [256, 4096, 65536]
 SHARDED_FULL_D = [1, 2, 4, 8]
 SHARDED_SMOKE_S = [32]
@@ -99,10 +100,9 @@ def _tile_bank(bank, order, reps):
     )
 
 
-def sharded_worker(args) -> None:
-    """Child-process body of the ``sharded`` section: time the S-scenario
-    tiled fleet on a ``--devices``-wide mesh (this process was launched with
-    that many virtual CPU devices) and print one JSON line."""
+def sharded_cell(d: int, s: int, seed: int) -> dict:
+    """One cell of the ``sharded`` section: time the S-scenario tiled fleet
+    on a ``d``-device mesh of this process and return its JSON entry."""
     import jax
     import numpy as np
 
@@ -110,21 +110,17 @@ def sharded_worker(args) -> None:
     from repro.core.scenarios import sample_scenarios
     from repro.core.workload import compile_bank
 
-    D, S, R = args.devices, args.shard_scenarios, SHARDED_REPLICAS
-    assert len(jax.devices()) == D, (len(jax.devices()), D)
-    pairs = sample_scenarios(n=SHARDED_BASE, seed=args.seed,
-                             scale=SHARDED_SCALE)
+    R = SHARDED_REPLICAS
+    pairs = sample_scenarios(n=SHARDED_BASE, seed=seed, scale=SHARDED_SCALE)
     base = compile_bank(pairs)
     # ascending tick bound -> contiguous length clusters after tiling
     order = np.argsort(np.asarray(base.max_ticks), kind="stable")
-    bank = _tile_bank(base, order, max(1, S // SHARDED_BASE))
+    bank = _tile_bank(base, order, max(1, s // SHARDED_BASE))
     params = make_bank_params(bank)
-    keys = jax.random.split(
-        jax.random.PRNGKey(args.seed), S * R
-    ).reshape(S, R, 2)
+    keys = jax.random.split(jax.random.PRNGKey(seed), s * R).reshape(s, R, 2)
 
     run = lambda: simulate_bank(
-        bank, params, keys, leap=True, bucketed=False, mesh=D
+        bank, params, keys, leap=True, bucketed=False, mesh=d
     )
     t0 = time.time()
     jax.block_until_ready(run())
@@ -136,43 +132,22 @@ def sharded_worker(args) -> None:
         jax.block_until_ready(out)
         warm = min(warm, time.time() - t0)
 
-    parity = S <= SHARDED_PARITY_MAX_S
+    parity = s <= SHARDED_PARITY_MAX_S
     if parity:
         ref = simulate_bank(bank, params, keys, leap=True, bucketed=False)
         for f in out._fields:
             a, b = np.asarray(getattr(ref, f)), np.asarray(getattr(out, f))
             assert np.array_equal(a, b), (
-                f"sharded (D={D}) vs unsharded mismatch in {f}"
+                f"sharded (D={d}) vs unsharded mismatch in {f}"
             )
-    print(json.dumps({
-        "scenarios": S,
-        "devices": D,
+    return {
+        "scenarios": s,
+        "devices": d,
         "cold_s": round(cold, 3),
         "warm_s": round(warm, 4),
-        "scenarios_per_sec": round(S / warm, 2),
+        "scenarios_per_sec": round(s / warm, 2),
         "parity_checked": parity,
-    }))
-
-
-def _spawn_sharded_worker(d: int, s: int, seed: int) -> dict:
-    env = dict(os.environ)
-    flags = [
-        f for f in env.get("XLA_FLAGS", "").split()
-        if not f.startswith("--xla_force_host_platform_device_count")
-    ]
-    flags.append(f"--xla_force_host_platform_device_count={d}")
-    env["XLA_FLAGS"] = " ".join(flags)
-    env["JAX_PLATFORMS"] = "cpu"
-    out = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--sharded-worker",
-         "--devices", str(d), "--shard-scenarios", str(s), "--seed", str(seed)],
-        capture_output=True, text=True, env=env, timeout=1800,
-    )
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"sharded worker (D={d}, S={s}) failed:\n{out.stdout}\n{out.stderr}"
-        )
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    }
 
 
 def main() -> None:
@@ -192,15 +167,7 @@ def main() -> None:
                     help="tiny fleet, all sections + assertions; writes "
                          "BENCH_smoke.json instead of the tracked report")
     ap.add_argument("--out", default=None)
-    ap.add_argument("--sharded-worker", action="store_true",
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--devices", type=int, default=1, help=argparse.SUPPRESS)
-    ap.add_argument("--shard-scenarios", type=int, default=256,
-                    help=argparse.SUPPRESS)
     args = ap.parse_args()
-    if args.sharded_worker:
-        sharded_worker(args)
-        return
     if args.smoke:
         args.scenarios, args.replicas, args.buckets = 8, 2, 2
         args.stream_chunks = 2
@@ -212,6 +179,9 @@ def main() -> None:
 
     from repro import Fleet
     from repro.core import engine as engine_lib
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.core.engine import (
         SimSpec,
         count_bank_traces,
@@ -451,40 +421,48 @@ def main() -> None:
     drain = lambda: [c.result.ticks for c in fleet.stream(iter(pairs2), **stream_kw)]
     with count_bank_traces() as stream_first:
         _, stream_cold = timed(drain)
+    stream_first_traces = stream_first.count
     with count_bank_traces() as stream_rest:
         _, stream_warm = timed_warm(drain)
     stream_retraces = stream_rest.count
 
     # ---- sharded fleet: scenarios/sec vs device count ---------------------
-    # each device count needs its own XLA device topology, so every (S, D)
-    # cell runs in a worker subprocess launched with
-    # --xla_force_host_platform_device_count=D; workers assert bitwise
+    # in-process over this process's devices; cells assert bitwise
     # sharded-vs-unsharded parity at S <= SHARDED_PARITY_MAX_S
+    n_dev = len(jax.devices())
     sharded_s = SHARDED_SMOKE_S if args.smoke else SHARDED_FULL_S
-    sharded_d = SHARDED_SMOKE_D if args.smoke else SHARDED_FULL_D
-    sharded_entries = []
-    for s in sharded_s:
-        for d in sharded_d:
-            entry = _spawn_sharded_worker(d, s, args.seed)
-            sharded_entries.append(entry)
-            print(f"sharded S={s} D={d}: "
-                  f"{entry['scenarios_per_sec']} scen/s", file=sys.stderr)
-    s_top = max(sharded_s)
-    tp = {
-        e["devices"]: e["scenarios_per_sec"]
-        for e in sharded_entries if e["scenarios"] == s_top
-    }
-    sharded_speedup = round(tp[max(sharded_d)] / tp[min(sharded_d)], 2)
-    sharded_section = {
-        "base_scenarios": SHARDED_BASE,
-        "replicas": SHARDED_REPLICAS,
-        "scale": SHARDED_SCALE,
-        "leap": True,
-        "device_counts": sharded_d,
-        "entries": sharded_entries,
-        "speedup_at_max_devices": sharded_speedup,
-        "speedup_fleet_scenarios": s_top,
-    }
+    sharded_d = [
+        d for d in (SHARDED_SMOKE_D if args.smoke else SHARDED_FULL_D)
+        if d <= n_dev
+    ]
+    sharded_speedup = None
+    if n_dev < 2:
+        sharded_section = {"skipped": f"{n_dev} device(s); needs 2 or more"}
+        print("sharded section skipped: fewer than 2 devices", file=sys.stderr)
+    else:
+        sharded_entries = []
+        for s in sharded_s:
+            for d in sharded_d:
+                entry = sharded_cell(d, s, args.seed)
+                sharded_entries.append(entry)
+                print(f"sharded S={s} D={d}: "
+                      f"{entry['scenarios_per_sec']} scen/s", file=sys.stderr)
+        s_top = max(sharded_s)
+        tp = {
+            e["devices"]: e["scenarios_per_sec"]
+            for e in sharded_entries if e["scenarios"] == s_top
+        }
+        sharded_speedup = round(tp[max(sharded_d)] / tp[min(sharded_d)], 2)
+        sharded_section = {
+            "base_scenarios": SHARDED_BASE,
+            "replicas": SHARDED_REPLICAS,
+            "scale": SHARDED_SCALE,
+            "leap": True,
+            "device_counts": sharded_d,
+            "entries": sharded_entries,
+            "speedup_at_max_devices": sharded_speedup,
+            "speedup_fleet_scenarios": s_top,
+        }
 
     # simulated work: sum over (scenario, replica) of real legs x ticks run
     legs = np.asarray(bank.n_legs, np.float64)
@@ -573,9 +551,9 @@ def main() -> None:
         f"{distinct_shapes} distinct bucket shapes"
     )
     assert fresh_retraces == 0, "fresh fleet must reuse every bucket trace"
-    assert stream_first.count == 1, (
+    assert stream_first_traces == 1, (
         f"cold stream must trace exactly once (all chunks share one "
-        f"fixed-pad shape), traced {stream_first.count}"
+        f"fixed-pad shape), traced {stream_first_traces}"
     )
     assert stream_retraces == 0, (
         "streamed chunks must reuse the first chunk's trace"
@@ -586,7 +564,7 @@ def main() -> None:
         f"model no longer predicts per-bucket walls "
         f"(rates: {sorted(norm_rates)})"
     )
-    if not args.smoke:
+    if not args.smoke and sharded_speedup is not None:
         assert sharded_speedup > 1.0, (
             f"sharding the S={s_top} fleet over {max(sharded_d)} devices "
             f"must beat 1 device, got {sharded_speedup}x"

@@ -9,6 +9,9 @@ import sys
 
 
 def main() -> None:
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import paper_experiments as paper
     from benchmarks import perf
 

@@ -33,9 +33,8 @@ finds its wide banks already warm — admits every remaining request with
 **zero** banked-engine retraces (a bank pre-traces its whole ladder at
 construction). On a multi-device host (the CI 8-virtual-device job) the
 server itself runs sharded (``devices=``), so the same assertions cover
-the sharded overlap-scheduling path; single-device full runs
-additionally spawn an 8-virtual-CPU worker subprocess for a sharded
-throughput section, and every run restarts a server against a
+the sharded overlap-scheduling path (a one-device run reports that the
+sharded mode was skipped), and every run restarts a server against a
 ``warm_dir`` store and asserts the restart loads templates and retraces
 nothing. ``--smoke`` writes ``BENCH_serve_smoke.json``; the tracked
 ``BENCH_serve.json`` is only rewritten by full runs. The report carries
@@ -53,7 +52,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -75,7 +73,6 @@ FULL = dict(requests=64, slots=2, replicas=4, rate=200.0, scale=4.0,
 # push steady p99 past the 826 ms floor (spill "capacity" in another
 # bank's idle lanes is an illusion when all banks serialize on one
 # device; the smoke keeps both paths covered).
-SHARDED_DEVICES = 8  # full-run worker subprocess (single-device hosts)
 SMOKE_BUCKETED_FLOOR = 0.05  # smoke-size serve/bucketed ratio guard: the
                              # tiny workload is pure host overhead against
                              # a compile-excluded device ceiling, so the
@@ -278,49 +275,6 @@ def warm_restart_section(args, workload, sig_of):
         }
 
 
-def sharded_worker(args) -> None:
-    """Child-process body of the full-run sharded section: same steady
-    phase on a ``--devices``-wide virtual-CPU mesh, one JSON line out."""
-    import jax
-
-    assert len(jax.devices()) == args.devices, (len(jax.devices()), args.devices)
-    workload, sig_of = _build_workload(args)
-    report, server, results = serve_section(
-        args, workload, sig_of, devices=args.devices
-    )
-    for _, req in workload[:2]:
-        _assert_parity(server, req, sig_of[req.rid])
-    print(json.dumps(report))
-
-
-def _spawn_sharded_worker(args) -> dict:
-    env = dict(os.environ)
-    flags = [
-        f for f in env.get("XLA_FLAGS", "").split()
-        if not f.startswith("--xla_force_host_platform_device_count")
-    ]
-    flags.append(
-        f"--xla_force_host_platform_device_count={SHARDED_DEVICES}"
-    )
-    env["XLA_FLAGS"] = " ".join(flags)
-    env["JAX_PLATFORMS"] = "cpu"
-    out = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--sharded-worker",
-         "--devices", str(SHARDED_DEVICES),
-         "--requests", str(args.requests), "--slots", str(args.slots),
-         "--replicas", str(args.replicas), "--rate", str(args.rate),
-         "--scale", str(args.scale), "--seed", str(args.seed)]
-        + (["--window", str(args.window)] if args.window else []),
-        capture_output=True, text=True, env=env, timeout=3600,
-    )
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"sharded serve worker (D={SHARDED_DEVICES}) failed:\n"
-            f"{out.stdout}\n{out.stderr}"
-        )
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
 def _build_workload(args):
     from repro.core.workload import compile_campaign
     from repro.serve import ServeConfig, synthetic_workload
@@ -352,31 +306,30 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--sharded-worker", action="store_true",
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--devices", type=int, default=None,
-                    help=argparse.SUPPRESS)
     args = ap.parse_args()
     for k, v in (SMOKE if args.smoke else FULL).items():
         if getattr(args, k, None) is None:
             setattr(args, k, v)
     if args.out is None:
         args.out = "BENCH_serve_smoke.json" if args.smoke else "BENCH_serve.json"
-    if args.sharded_worker:
-        sharded_worker(args)
-        return
 
     import jax
 
     from repro.core.fleet import Fleet
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     t_start = time.time()
     workload, sig_of = _build_workload(args)
     pairs = [(req.grid, req.campaign) for _, req in workload]
     n = len(pairs)
 
-    # -- served: in-process (sharded in-process when the host has devices) --
+    # -- served: sharded over this process's devices when it has several ---
     devices = jax.device_count() if jax.device_count() > 1 else None
+    if devices is None:
+        print("sharded serving skipped: 1 device; the server runs unsharded",
+              file=sys.stderr)
     serve_report, server, results = serve_section(
         args, workload, sig_of, devices=devices
     )
@@ -445,8 +398,6 @@ def main() -> None:
         "metrics": server.metrics(),
     }
     report["warm_restart"] = warm_restart_section(args, workload, sig_of)
-    if not args.smoke and jax.device_count() == 1:
-        report["sharded"] = _spawn_sharded_worker(args)
     report["total_s"] = round(time.time() - t_start, 1)
 
     with open(args.out, "w") as f:
@@ -456,9 +407,6 @@ def main() -> None:
     assert serve_report["steady_retraces"] == 0
     _assert_obs_fields(report["served"], "served")
     _assert_obs_fields(report["warm_restart"], "warm_restart")
-    if "sharded" in report:
-        _assert_obs_fields(report["sharded"], "sharded")
-        assert report["sharded"]["steady_retraces"] == 0
     if args.smoke:
         # modest smoke floor: the tiny workload (light rows, 1 replica)
         # maximizes host overhead per unit of device work, so the served /

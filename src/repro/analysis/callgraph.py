@@ -40,6 +40,7 @@ TRACING_HOFS = frozenset(
         "jax.remat",
         "jax.grad",
         "jax.value_and_grad",
+        "jax.shard_map",
         "jax.experimental.shard_map.shard_map",
         "jax.experimental.pallas.pallas_call",
     }

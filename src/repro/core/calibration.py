@@ -108,6 +108,7 @@ class CalibrationResult(NamedTuple):
     classifier_params: dict
     x_true: jax.Array  # [3]
     rhat: jax.Array = None  # [3] split-R-hat convergence diagnostic
+    epoch_loss: jax.Array = None  # [epochs] classifier training curve
 
 
 @dataclasses.dataclass
@@ -700,6 +701,7 @@ def calibrate(
         classifier_params=params,
         x_true=x_true,
         rhat=rhat,
+        epoch_loss=metrics.epoch_loss,
     )
 
 

@@ -130,8 +130,11 @@ def bce_loss(
 
 
 class TrainMetrics(NamedTuple):
-    loss: jax.Array
-    accuracy: jax.Array
+    loss: jax.Array  # last minibatch
+    accuracy: jax.Array  # last minibatch
+    # mean minibatch loss of the epoch (``_train_epoch``), or ``[epochs]``
+    # of them — the training curve (``train_classifier``)
+    epoch_loss: jax.Array | None = None
 
 
 def _make_batch(
@@ -208,7 +211,9 @@ def _train_epoch(
     (params, opt_state), ms = jax.lax.scan(
         step, (params, opt_state), (starts, step_keys)
     )
-    metrics = TrainMetrics(loss=ms.loss[-1], accuracy=ms.accuracy[-1])
+    metrics = TrainMetrics(
+        loss=ms.loss[-1], accuracy=ms.accuracy[-1], epoch_loss=jnp.mean(ms.loss)
+    )
     return params, opt_state, metrics
 
 
@@ -249,10 +254,14 @@ def train_classifier(
     opt_state = adamw_init(params, AdamWConfig(lr=cfg.lr))
     lr = jnp.asarray(cfg.lr, jnp.float32)
     metrics = TrainMetrics(jnp.asarray(0.0), jnp.asarray(0.0))
+    curve = []
     for _ in range(epochs):
         key, epoch_key = jax.random.split(key)
         params, opt_state, metrics = _train_epoch(
             params, opt_state, theta, x, context, epoch_key, lr,
             batch_size=batch_size,
         )
-    return params, metrics
+        curve.append(metrics.epoch_loss)
+    return params, metrics._replace(
+        epoch_loss=jnp.stack(curve) if curve else jnp.zeros((0,))
+    )

@@ -21,7 +21,6 @@ import sys
 from typing import Callable, Iterator, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.experimental.shard_map import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 import numpy as np
@@ -34,7 +33,7 @@ from repro.core.workload import (
     PAD_PROTOCOL,
     ScenarioBank,
 )
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 __all__ = [
     "SimSpec",
@@ -160,7 +159,7 @@ def _leap_body(
     resample) the fair-share rates are constant, so a whole inter-event
     window of ``dt`` ticks is applied in closed form: ``dt-1`` rate-exact
     ticks plus the (possibly clipped) final tick. One ``grid_tick`` rate
-    evaluation plus two small one-hot matmuls per window replaces ``dt``
+    evaluation plus two small one-hot sums per window replaces ``dt``
     full tick evaluations; results are bit-comparable to the tick loop for
     deterministic background loads (see tests/benchmarks: ~10x).
 
@@ -217,14 +216,16 @@ def _leap_body(
     # dt-1 rate-exact ticks + the final (possibly clipped) tick
     rem_mid = c.remaining - a * rate * (dt - 1.0)
     xfer_f = jnp.minimum(rem_mid, rate) * a
-    proc_xfer_f = xfer_f @ spec.leg_proc
-    link_xfer_f = xfer_f @ spec.leg_link
+    proc_xfer_f = ref.onehot_sum(xfer_f, spec.leg_proc)
+    link_xfer_f = ref.onehot_sum(xfer_f, spec.leg_link)
     remaining = rem_mid - xfer_f
 
-    own_proc_rate = spec.leg_proc @ proc_rate
-    own_link_rate = spec.leg_link @ link_rate
-    own_proc_f = spec.leg_proc @ proc_xfer_f
-    own_link_f = spec.leg_link @ link_xfer_f
+    proc_of_leg = ref.leg_index(spec.leg_proc)
+    link_of_leg = ref.leg_index(spec.leg_link)
+    own_proc_rate = ref.gather_legs(proc_rate, proc_of_leg)
+    own_link_rate = ref.gather_legs(link_rate, link_of_leg)
+    own_proc_f = ref.gather_legs(proc_xfer_f, proc_of_leg)
+    own_link_f = ref.gather_legs(link_xfer_f, link_of_leg)
     conth = c.conth + a * ((own_proc_rate - rate) * (dt - 1.0)
                            + (own_proc_f - xfer_f))
     conpr = c.conpr + a * ((own_link_rate - own_proc_rate) * (dt - 1.0)
@@ -299,8 +300,8 @@ def _tick_body(
     #   ConTh — traffic of the *other threads of the same process* while the
     #           leg is active;
     #   ConPr — traffic of *other campaign processes on the same link*.
-    own_proc_xfer = spec.leg_proc @ proc_xfer  # [T]
-    own_link_xfer = spec.leg_link @ link_xfer  # [T]
+    own_proc_xfer = ref.gather_legs(proc_xfer, ref.leg_index(spec.leg_proc))
+    own_link_xfer = ref.gather_legs(link_xfer, ref.leg_index(spec.leg_link))
     conth = c.conth + a * (own_proc_xfer - xfer)
     conpr = c.conpr + a * (own_link_xfer - own_proc_xfer)
 
@@ -947,7 +948,7 @@ def _simulate_bank_sharded(
     per-element freeze masks and per-element RNG streams are untouched by
     the partitioning, and the result is **bit-identical** to the unsharded
     run in stable scenario order (the pad rows are sliced off before
-    returning). ``check_rep=False`` because replication checking has
+    returning). ``check_vma=False`` because replication checking has
     nothing to verify in a collective-free program (and per-shard
     while-loop trip counts legitimately differ).
     """
@@ -966,8 +967,8 @@ def _simulate_bank_sharded(
     core = _vmap_bank_core if lowering == "vmap" else _banked_core
     fn = functools.partial(core, backend=backend, leap=leap, window=window)
     p = PartitionSpec(axis)
-    out = shard_map(
-        fn, mesh=mesh, in_specs=(p, p, p), out_specs=p, check_rep=False
+    out = jax.shard_map(
+        fn, mesh=mesh, in_specs=(p, p, p), out_specs=p, check_vma=False
     )(spec, params, keys)
     if pad:
         out = jax.tree.map(lambda a: a[:s], out)
@@ -1023,7 +1024,7 @@ def _banked_window_step_sharded(
     keep their scenario axis a multiple of the mesh size by construction,
     so the step stays a pure ``[S/D, R, ...]``-per-device window body with
     zero collectives and the same bit-exact freeze semantics as the
-    unsharded step. ``check_rep=False`` for the same reason as the
+    unsharded step. ``check_vma=False`` for the same reason as the
     monolithic sharded program: there is nothing replicated to verify.
     """
     global _bank_traces
@@ -1039,8 +1040,8 @@ def _banked_window_step_sharded(
         return _bank_window_body(sp, pa, backend, leap, window, ca)
 
     p = PartitionSpec(mesh.axis_names[0])
-    return shard_map(
-        body, mesh=mesh, in_specs=(p, p, p), out_specs=p, check_rep=False
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(p, p, p), out_specs=p, check_vma=False
     )(spec, params, carry)
 
 
@@ -1113,9 +1114,9 @@ def _admit_bank_rows_sharded(
         return _Carry(*(merge(n, o) for n, o in zip(fresh, ca)))
 
     p = PartitionSpec(mesh.axis_names[0])
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(p, p, p, p, p), out_specs=p,
-        check_rep=False,
+        check_vma=False,
     )(spec, params, keys, carry, mask)
 
 
@@ -1148,13 +1149,13 @@ def _bank_snapshot(spec: SimSpec, carry: _Carry):
 @functools.partial(jax.jit, static_argnames=("mesh",))
 def _bank_snapshot_sharded(spec: SimSpec, carry: _Carry, *, mesh: Mesh):
     """Sharded twin of :func:`_bank_snapshot` (row-local, collective-free;
-    ``check_rep=False`` as for the other sharded bank programs)."""
+    ``check_vma=False`` as for the other sharded bank programs)."""
     global _bank_traces
     _bank_traces += 1  # executes at trace time only
     p = PartitionSpec(mesh.axis_names[0])
-    return shard_map(
+    return jax.shard_map(
         _bank_snapshot_body, mesh=mesh, in_specs=(p, p), out_specs=(p, p),
-        check_rep=False,
+        check_vma=False,
     )(spec, carry)
 
 

@@ -43,12 +43,15 @@ def ols_no_intercept(
         w = weights.astype(X.dtype)
     Xw = X * w[:, None]
     yw = y * w
-    xtx = Xw.T @ Xw
-    xty = Xw.T @ yw
-    # ridge epsilon for numerical safety on near-collinear masks
-    eye = jnp.eye(k, dtype=X.dtype)
-    coef = jnp.linalg.solve(xtx + 1e-8 * eye, xty)
-    resid = (yw - Xw @ coef) * 1.0
+    # full f32 in the normal equations and the solve: a TPU runs f32 dots
+    # at one bf16 pass by default, and X^T X squares the regressors' range
+    with jax.default_matmul_precision("highest"):
+        xtx = Xw.T @ Xw
+        xty = Xw.T @ yw
+        # ridge epsilon for numerical safety on near-collinear masks
+        eye = jnp.eye(k, dtype=X.dtype)
+        coef = jnp.linalg.solve(xtx + 1e-8 * eye, xty)
+        resid = (yw - Xw @ coef) * 1.0
     n_eff = jnp.sum(w)
     ss_res = jnp.sum(resid**2)
     ss_tot = jnp.sum(yw**2)  # uncentered: no-intercept convention (as in R)

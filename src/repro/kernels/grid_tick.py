@@ -49,6 +49,28 @@ _LANE = 128
 _SUBLANE = 8
 
 
+def _dot(a: jax.Array, b: jax.Array, *, exact: bool = False) -> jax.Array:
+    """``[M, K] x [K, N]`` on the MXU. ``exact=True`` for contractions that
+    carry MB values or counts above 256: an f32 dot otherwise defaults to
+    one bf16 pass, which rounds every operand to 8 mantissa bits. The 0/1
+    mask contractions are exact in one pass."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if exact else None,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _dot_t(a: jax.Array, b: jax.Array, *, exact: bool = False) -> jax.Array:
+    """``[M, K] x [N, K]^T`` (gathers against a transposed incidence); see
+    :func:`_dot` for ``exact``."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if exact else None,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _pad_to(x: jax.Array, axis: int, mult: int, value: float = 0) -> jax.Array:
     size = x.shape[axis]
     target = -(-size // mult) * mult
@@ -79,36 +101,19 @@ def _tick_kernel(
     m_pl = m_pl_ref[...]
     m_tl = m_tl_ref[...]
 
-    # threads per process: [Bb, P]
-    threads = jax.lax.dot_general(
-        active, m_tp, (((1,), (0,)), ((), ())), preferred_element_type=f32
-    )
+    threads = _dot(active, m_tp)  # threads per process: [Bb, P]
     proc_active = (threads > 0).astype(f32)
-    # campaign processes per link: [Bb, L]
-    campaign = jax.lax.dot_general(
-        proc_active, m_pl, (((1,), (0,)), ((), ())), preferred_element_type=f32
-    )
+    campaign = _dot(proc_active, m_pl)  # campaign processes per link: [Bb, L]
     denom = jnp.maximum(campaign + jnp.maximum(bg_ref[...].astype(f32), 0.0), 1.0)
     per_proc = bw_ref[...].astype(f32) / denom  # [Bb, L]
     # gather to legs: one-hot matmuls against the transposed incidences
-    per_proc_leg = jax.lax.dot_general(
-        per_proc, m_tl, (((1,), (1,)), ((), ())), preferred_element_type=f32
-    )  # [Bb, T]
-    threads_leg = jnp.maximum(
-        jax.lax.dot_general(
-            threads, m_tp, (((1,), (1,)), ((), ())), preferred_element_type=f32
-        ),
-        1.0,
-    )  # [Bb, T]
+    per_proc_leg = _dot_t(per_proc, m_tl, exact=True)  # [Bb, T]
+    threads_leg = jnp.maximum(_dot_t(threads, m_tp, exact=True), 1.0)
     chunk = active * keep_ref[...].astype(f32) * per_proc_leg / threads_leg
     xfer = jnp.minimum(remaining, chunk)
     xfer_ref[...] = xfer
-    proc_ref[...] = jax.lax.dot_general(
-        xfer, m_tp, (((1,), (0,)), ((), ())), preferred_element_type=f32
-    )
-    link_ref[...] = jax.lax.dot_general(
-        xfer, m_tl, (((1,), (0,)), ((), ())), preferred_element_type=f32
-    )
+    proc_ref[...] = _dot(xfer, m_tp, exact=True)
+    link_ref[...] = _dot(xfer, m_tl, exact=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_b"))
@@ -218,24 +223,18 @@ def _bank_tick_kernel(
     m_pl = m_pl_ref[0]
     m_tl = m_tl_ref[0]
 
-    dot = lambda a, b: jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=f32
-    )
-    dot_t = lambda a, b: jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=f32
-    )
-    threads = dot(active, m_tp)  # [Rb, P]
+    threads = _dot(active, m_tp)  # [Rb, P]
     proc_active = (threads > 0).astype(f32)
-    campaign = dot(proc_active, m_pl)  # [Rb, L]
+    campaign = _dot(proc_active, m_pl)  # [Rb, L]
     denom = jnp.maximum(campaign + jnp.maximum(bg_ref[0].astype(f32), 0.0), 1.0)
     per_proc = bw_ref[0].astype(f32) / denom  # [Rb, L]
-    per_proc_leg = dot_t(per_proc, m_tl)  # [Rb, T]
-    threads_leg = jnp.maximum(dot_t(threads, m_tp), 1.0)  # [Rb, T]
+    per_proc_leg = _dot_t(per_proc, m_tl, exact=True)  # [Rb, T]
+    threads_leg = jnp.maximum(_dot_t(threads, m_tp, exact=True), 1.0)
     chunk = active * keep_ref[0].astype(f32) * per_proc_leg / threads_leg
     xfer = jnp.minimum(remaining, chunk)
     xfer_ref[0] = xfer
-    proc_ref[0] = dot(xfer, m_tp)
-    link_ref[0] = dot(xfer, m_tl)
+    proc_ref[0] = _dot(xfer, m_tp, exact=True)
+    link_ref[0] = _dot(xfer, m_tl, exact=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_r"))
@@ -382,13 +381,6 @@ def _bank_fused_kernel(
     m_pl = m_pl_ref[0]
     m_tl = m_tl_ref[0]
 
-    dot = lambda a, b: jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=f32
-    )
-    dot_t = lambda a, b: jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=f32
-    )
-
     def alive_of(t, done):  # [Rb, 1] bool
         all_done = jnp.min(done, axis=1, keepdims=True) > 0.5
         return (t[:, :1] < mt) & ~all_done
@@ -405,24 +397,24 @@ def _bank_fused_kernel(
 
         # dep[t] gather as a one-hot matmul (MXU): column t of mdep selects
         # done[dep[t]]; legs without a dependency get the nodep bias instead
-        dep_ok = (dot(done, mdep) + nodep) > 0.5
+        dep_ok = (_dot(done, mdep) + nodep) > 0.5
         active = (done < 0.5) & (release <= t_col) & dep_ok & alive
         a = active.astype(f32)
 
-        threads = dot(a, m_tp)  # [Rb, P]
+        threads = _dot(a, m_tp)  # [Rb, P]
         proc_active = (threads > 0).astype(f32)
-        campaign = dot(proc_active, m_pl)  # [Rb, L]
+        campaign = _dot(proc_active, m_pl)  # [Rb, L]
         denom = jnp.maximum(campaign + jnp.maximum(bg, 0.0), 1.0)
         per_proc = bw / denom  # [Rb, L]
-        per_proc_leg = dot_t(per_proc, m_tl)  # [Rb, T]
-        threads_leg = jnp.maximum(dot_t(threads, m_tp), 1.0)
+        per_proc_leg = _dot_t(per_proc, m_tl, exact=True)  # [Rb, T]
+        threads_leg = jnp.maximum(_dot_t(threads, m_tp, exact=True), 1.0)
         chunk = a * keep * per_proc_leg / threads_leg
         xfer = jnp.minimum(remaining, chunk)
-        proc_xfer = dot(xfer, m_tp)
-        link_xfer = dot(xfer, m_tl)
+        proc_xfer = _dot(xfer, m_tp, exact=True)
+        link_xfer = _dot(xfer, m_tl, exact=True)
 
-        own_proc = dot_t(proc_xfer, m_tp)  # [Rb, T]
-        own_link = dot_t(link_xfer, m_tl)
+        own_proc = _dot_t(proc_xfer, m_tp, exact=True)  # [Rb, T]
+        own_link = _dot_t(link_xfer, m_tl, exact=True)
         conth = conth + a * (own_proc - xfer)
         conpr = conpr + a * (own_link - own_proc)
         remaining = remaining - xfer

@@ -223,7 +223,7 @@ def grid_tick_bank_fused(
     the RNG keys ride per-element in the carry.  The windowed engine relies
     on this when it wraps the window loop in ``shard_map`` over a scenario
     mesh (``simulate_bank(..., mesh=)``): each shard sees an ordinary
-    smaller bank, needs no collectives (``check_rep=False``), and produces
+    smaller bank, needs no collectives (``check_vma=False``), and produces
     bitwise the rows it would produce unsharded.  Keep new window-body ops
     row-local or the sharded engine's bitwise-parity contract breaks.
     """
@@ -381,6 +381,7 @@ def selu_mlp(
         return ref.selu_mlp(x, weights, biases)
     from repro.kernels import selu_mlp as _k
 
-    return _k.selu_mlp_pallas(
-        x, weights, biases, interpret=(b == "pallas_interpret")
+    # differentiable: kernel forward, reverse pass through the XLA reference
+    return _k.selu_mlp(
+        x, tuple(weights), tuple(biases), b == "pallas_interpret"
     )

@@ -13,6 +13,9 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
+    "onehot_sum",
+    "leg_index",
+    "gather_legs",
     "grid_tick",
     "grid_tick_bank_window",
     "flash_attention",
@@ -20,6 +23,73 @@ __all__ = [
     "mlstm_chunk",
     "selu_mlp",
 ]
+
+
+# ---------------------------------------------------------------------------
+# one-hot contractions of per-leg values
+# ---------------------------------------------------------------------------
+#: Lane count of :func:`onehot_sum`'s summation order. Changing it changes
+#: the last ulp of every concurrency accumulator.
+SUM_LANES = 16
+
+
+def onehot_sum(v: jax.Array, m: jax.Array) -> jax.Array:
+    """Per-process / per-link sums of a per-leg quantity:
+    ``[..., T] x [..., T, X] -> [..., X]``.
+
+    Written as explicit adds in a fixed order, not as a matmul or a
+    reduction, for two reasons. The summation order of a dot or a reduce is
+    the backend's choice and follows the padded leg width and the batch
+    shape, so the same scenario summed at two bank layouts (bucket pads vs
+    monolithic pads, a shard vs the whole bank, a server slot bank vs a
+    one-row fleet) would drift in the last ulp. And an f32 dot on a TPU
+    runs at reduced MXU precision by default, which would round MB values
+    to bf16; the products here are exact on every backend.
+
+    The order: leg ``t`` goes to lane ``t % SUM_LANES``; each lane adds its
+    legs in index order, block by block of ``SUM_LANES`` legs, and the lanes
+    are then added in lane order. A compiler does not reassociate float
+    adds it was given explicitly, and a wider pad only appends blocks of
+    exact zeros to every lane, so the result does not depend on the pad
+    width. The program holds ``T / SUM_LANES + SUM_LANES`` adds, each over
+    a ``[..., SUM_LANES, X]`` slab, which fuse into one loop.
+    """
+    n = v.shape[-1]
+    blocks = -(-n // SUM_LANES)
+    if blocks * SUM_LANES > n:
+        v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, blocks * SUM_LANES - n)])
+        m = jnp.pad(m, [(0, 0)] * (m.ndim - 2)
+                    + [(0, blocks * SUM_LANES - n), (0, 0)])
+    block = lambda k: (
+        v[..., k * SUM_LANES:(k + 1) * SUM_LANES, None]
+        * m[..., k * SUM_LANES:(k + 1) * SUM_LANES, :]
+    )
+    lanes = block(0)  # [..., SUM_LANES, X]
+    for k in range(1, blocks):
+        lanes = lanes + block(k)
+    acc = lanes[..., 0, :]
+    for j in range(1, SUM_LANES):
+        acc = acc + lanes[..., j, :]
+    return acc
+
+
+def leg_index(m: jax.Array) -> jax.Array:
+    """Process / link index of each leg from a one-hot incidence:
+    ``[..., T, X] -> [..., T]`` i32 (0 for an all-zero padding row)."""
+    return jnp.argmax(m, axis=-1).astype(jnp.int32)
+
+
+def gather_legs(v: jax.Array, idx: jax.Array) -> jax.Array:
+    """Gather per-process / per-link values back to legs by their
+    :func:`leg_index`: ``[..., X] x [..., T] -> [..., T]``, batch dims
+    broadcast. Exact, the same as the one-hot matmul on real legs; a
+    padding leg reads entry 0, so callers mask it by ``active``."""
+    batch = jnp.broadcast_shapes(v.shape[:-1], idx.shape[:-1])
+    return jnp.take_along_axis(
+        jnp.broadcast_to(v, batch + v.shape[-1:]),
+        jnp.broadcast_to(idx, batch + idx.shape[-1:]),
+        axis=-1,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -50,22 +120,20 @@ def grid_tick(
     """
     f32 = jnp.float32
     active = active.astype(f32)
-    # one-hot contractions as batched matmuls: [..., 1, T] @ [..., T, P]
-    row = lambda v, m: jnp.matmul(v[..., None, :], m)[..., 0, :]
-    # gathers against the transposed incidence: [..., 1, X] @ [..., X, T]^T
-    col = lambda v, m: jnp.matmul(v[..., None, :], jnp.swapaxes(m, -1, -2))[..., 0, :]
-    threads_per_proc = row(active, leg_proc)  # [..., P]
+    threads_per_proc = onehot_sum(active, leg_proc)  # [..., P]
     proc_is_active = (threads_per_proc > 0).astype(f32)
-    campaign_load = row(proc_is_active, proc_link)  # [..., L]
+    campaign_load = onehot_sum(proc_is_active, proc_link)  # [..., L]
     denom = jnp.maximum(campaign_load + jnp.maximum(bg_load, 0.0), 1.0)
     per_proc_bw = bandwidth / denom  # [..., L]
-    # gather link/process quantities back to legs (one-hot matvecs)
-    per_proc_bw_leg = col(per_proc_bw, leg_link)  # [..., T]
-    threads_leg = jnp.maximum(col(threads_per_proc, leg_proc), 1.0)  # [..., T]
+    # gather link/process quantities back to legs
+    per_proc_bw_leg = gather_legs(per_proc_bw, leg_index(leg_link))  # [..., T]
+    threads_leg = jnp.maximum(
+        gather_legs(threads_per_proc, leg_index(leg_proc)), 1.0
+    )
     chunk = active * keep_frac * per_proc_bw_leg / threads_leg
     xfer = jnp.minimum(remaining, chunk)
-    proc_xfer = row(xfer, leg_proc)  # [..., P]
-    link_xfer = row(xfer, leg_link)  # [..., L]
+    proc_xfer = onehot_sum(xfer, leg_proc)  # [..., P]
+    link_xfer = onehot_sum(xfer, leg_link)  # [..., L]
     return xfer, proc_xfer, link_xfer
 
 
@@ -168,14 +236,13 @@ def grid_tick_bank_window(
     to dt=1). ``tick`` is the bank fair-share kernel to drive (the
     ``ops.grid_tick_bank`` signature); keeping it injectable lets the
     interpret-mode kernel and the TPU kernel share this scan. With
-    ``tick=None`` the window runs its built-in **index-based** fair-share
-    tick: because the incidence matrices are one-hot, every gather-direction
+    ``tick=None`` the window runs its built-in fair-share tick. Either way
+    the incidence matrices are one-hot, so every gather-direction
     contraction (process/link quantities back to legs) is a
     ``take_along_axis`` by the precomputed ``argmax`` index — bit-identical
-    to the one-hot matmul (a dot against a one-hot row sums one term and
-    zeros) but an order of magnitude cheaper than tiny batched matmuls on
-    CPU/GPU — and the two scatter-direction sums share one concatenated
-    incidence matmul. TPU paths keep the MXU-friendly einsum forms.
+    to the one-hot matmul, and cheaper than tiny batched matmuls — and the
+    scatter-direction sums are :func:`onehot_sum`, whose result does not
+    depend on the bank layout or on the backend's matmul precision.
     """
     f32 = jnp.float32
     i32 = jnp.int32
@@ -188,31 +255,26 @@ def grid_tick_bank_window(
         raise ValueError("grid_tick_bank_window: key= mode requires window=")
     n_links = bg_mu.shape[-1]
 
+    # index tables for the gather-direction contractions (process/link
+    # quantities back to legs), computed once, outside the scan; the
+    # scatter direction is a fixed-order one-hot sum (onehot_sum)
+    proc_of_leg = leg_index(leg_proc)[:, None]  # [S, 1, T]
+    link_of_leg = leg_index(leg_link)[:, None]  # [S, 1, T]
+    m_cat = jnp.concatenate([leg_proc, leg_link], axis=-1)[:, None]
+    n_procs = leg_proc.shape[-1]
+
+    leg_from_proc = lambda v: gather_legs(v, proc_of_leg)
+    leg_from_link = lambda v: gather_legs(v, link_of_leg)
+
+    def scatter_pl(v: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """Per-process and per-link sums of a per-leg quantity, as one sum
+        against the concatenated incidences (each column is summed on its
+        own, so the bits equal two separate sums)."""
+        both = onehot_sum(v, m_cat)
+        return both[..., :n_procs], both[..., n_procs:]
+
     if tick is None:
-        # index-based CPU/GPU lowering of the one-hot contractions; the
-        # index tables and the concatenated scatter incidence are computed
-        # once, outside the scan
-        proc_of_leg = jnp.argmax(leg_proc, axis=-1).astype(i32)  # [S, T]
-        link_of_leg = jnp.argmax(leg_link, axis=-1).astype(i32)  # [S, T]
-        m_cat = jnp.concatenate([leg_proc, leg_link], axis=-1)  # [S,T,P+L]
-        n_procs = leg_proc.shape[-1]
         keep3 = keep_frac if keep_frac.ndim == 3 else keep_frac[:, None]
-
-        def to_legs(v: jax.Array, idx: jax.Array) -> jax.Array:
-            """Gather per-proc/link values back to legs: [S, R, X] -> [S, R, T]."""
-            full = jnp.broadcast_to(
-                idx[:, None, :], v.shape[:2] + idx.shape[-1:]
-            )
-            return jnp.take_along_axis(v, full, axis=2)
-
-        leg_from_proc = lambda v: to_legs(v, proc_of_leg)
-        leg_from_link = lambda v: to_legs(v, link_of_leg)
-
-        def scatter_pl(v: jax.Array) -> Tuple[jax.Array, jax.Array]:
-            """Per-process and per-link sums of a per-leg quantity, as one
-            batched matmul against the concatenated one-hot incidences."""
-            both = jnp.einsum("srt,stx->srx", v, m_cat)
-            return both[..., :n_procs], both[..., n_procs:]
 
         def tick(a, remaining, _keep, bg, bandwidth_, _lp, _pl, _ll):
             threads = jnp.einsum("srt,stp->srp", a, leg_proc)
@@ -226,13 +288,6 @@ def grid_tick_bank_window(
             xfer = jnp.minimum(remaining, chunk)
             proc_xfer, link_xfer = scatter_pl(xfer)
             return xfer, proc_xfer, link_xfer
-    else:
-        leg_from_proc = lambda v: jnp.einsum("stp,srp->srt", leg_proc, v)
-        leg_from_link = lambda v: jnp.einsum("stl,srl->srt", leg_link, v)
-        scatter_pl = lambda v: (
-            jnp.einsum("srt,stp->srp", v, leg_proc),
-            jnp.einsum("srt,stl->srl", v, leg_link),
-        )
 
     def step(carry, noise_t):
         (t, steps, remaining, done, started, t_start, t_end, conth, conpr,

@@ -8,6 +8,12 @@ and chains the layers on the MXU without touching HBM in between.
 Feature dimensions are zero-padded to lane width by the wrapper; SELU(0) = 0,
 and zero-padded weight rows/cols contribute nothing, so padding is inert
 through every hidden layer (biases are zero in padded columns).
+
+Mosaic kernels have no reverse-mode rule, so :func:`selu_mlp` pairs the
+kernel forward with a backward pass through the XLA reference
+(:func:`repro.kernels.ref.selu_mlp`, re-run from the saved inputs): the
+classifier trains with the kernel in its forward pass on a TPU, and the
+MCMC scores with the same kernel.
 """
 from __future__ import annotations
 
@@ -18,7 +24,9 @@ import jax
 from jax.experimental import pallas as pl
 import jax.numpy as jnp
 
-__all__ = ["selu_mlp_pallas"]
+from repro.kernels import ref
+
+__all__ = ["selu_mlp", "selu_mlp_pallas"]
 
 _LANE = 128
 _ALPHA = 1.6732632423543772848170429916717
@@ -100,3 +108,25 @@ def selu_mlp_pallas(
         interpret=interpret,
     )(xp, *wp, *bp)
     return out[:N, :f_out].astype(dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def selu_mlp(
+    x: jax.Array,
+    weights: Tuple[jax.Array, ...],
+    biases: Tuple[jax.Array, ...],
+    interpret: bool = False,
+) -> jax.Array:
+    """Differentiable :func:`selu_mlp_pallas`: kernel forward, XLA backward."""
+    return selu_mlp_pallas(x, weights, biases, interpret=interpret)
+
+
+def _selu_mlp_fwd(x, weights, biases, interpret):
+    return selu_mlp(x, weights, biases, interpret), (x, weights, biases)
+
+
+def _selu_mlp_bwd(interpret, saved, g):
+    return jax.vjp(ref.selu_mlp, *saved)[1](g)
+
+
+selu_mlp.defvjp(_selu_mlp_fwd, _selu_mlp_bwd)

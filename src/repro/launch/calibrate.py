@@ -1,7 +1,7 @@
 """Calibration launcher (the paper's Section-5 pipeline at configurable
-scale). Presimulation is sharded across all local devices via vmapped batch
-simulation; on a pod the same code runs under the production mesh with the
-batch dimension sharded over (pod, data, model).
+scale). Presimulation runs in vmapped chunks of the per-campaign engine
+(``calibration.presimulate``) on the process's default device; it is not
+sharded across devices.
 
     PYTHONPATH=src python -m repro.launch.calibrate --presim 8192 \
         --epochs 120 --mcmc 8000 --validate 64 --replicates 4
@@ -34,6 +34,9 @@ def main() -> None:
                     help="synthetic ground truth used to generate x_true")
     ap.add_argument("--out", default="reports/calibration.json")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.core.calibration import (
         CalibrationConfig, calibrate, make_theta_mapper,

@@ -243,6 +243,9 @@ def main() -> None:
                     help="opt_flags: hoist_rope bf16_boundary gqa_grouped")
     ap.add_argument("--out", default="reports/dryrun")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     archs = list_archs() if (args.all or not args.arch) else [args.arch]
     shapes = list(SHAPES) if (args.all or not args.shape) else [args.shape]

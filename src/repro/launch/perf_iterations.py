@@ -48,6 +48,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="reports/perf")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     os.makedirs(args.out, exist_ok=True)
     results = []
     for arch, shape, name, kw in EXPERIMENTS:

@@ -159,6 +159,9 @@ def main() -> None:
     ap.add_argument("--out", default="reports/roofline.md")
     ap.add_argument("--json", default="reports/roofline.json")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rows = build_table(args.reports)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:

@@ -39,6 +39,9 @@ def main() -> None:
     ap.add_argument("--warm-dir", default=None,
                     help="slot-template warm store (Fleet.save format)")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.serve import ServeConfig, SimServer, synthetic_workload
 

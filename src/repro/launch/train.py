@@ -39,6 +39,9 @@ def main() -> None:
     ap.add_argument("--overlap-flags", action="store_true",
                     help="append the TPU latency-hiding XLA flags")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.overlap_flags:
         os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + _OVERLAP_FLAGS

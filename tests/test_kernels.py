@@ -101,6 +101,44 @@ def test_grid_tick_bank_matches_oracle(S, R, T, P, L):
                                    rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("T,X", [(1, 3), (37, 5), (58, 19), (106, 11)])
+def test_onehot_sum_is_bitwise_across_pads_and_batches(T, X):
+    """The same legs summed at any zero-padded width and any batch shape
+    give the same bits, within float rounding of a float64 sum."""
+    m = np.zeros((T, X), np.float32)
+    m[np.arange(T), RNG.randint(0, X, T)] = 1
+    v = RNG.uniform(0.0, 3000.0, (4, T)).astype(np.float32)
+    want = v.astype(np.float64) @ m.astype(np.float64)
+    base = np.asarray(ref.onehot_sum(jnp.asarray(v), jnp.asarray(m)))
+    np.testing.assert_allclose(base, want, rtol=1e-6)
+    for width in (T + 1, 2 * T + 3, 640):
+        vp = np.pad(v, ((0, 0), (0, width - T)))
+        mp = np.pad(m, ((0, width - T), (0, 0)))
+        padded = np.asarray(ref.onehot_sum(jnp.asarray(vp), jnp.asarray(mp)))
+        assert np.array_equal(padded, base), width
+        one = np.asarray(ref.onehot_sum(jnp.asarray(vp[2]), jnp.asarray(mp)))
+        assert np.array_equal(one, base[2]), width
+        stacked = np.asarray(ref.onehot_sum(
+            jnp.asarray(np.stack([vp, vp])), jnp.asarray(mp[None, None])
+        ))
+        assert np.array_equal(stacked[1], base), width
+
+
+def test_gather_legs_matches_onehot_matmul():
+    """Index gathers equal the one-hot matmul exactly on real legs, with
+    broadcast batch dims."""
+    S, R, T, X = 3, 2, 23, 7
+    m = np.zeros((S, T, X), np.float32)
+    for s in range(S):
+        m[s, np.arange(T), RNG.randint(0, X, T)] = 1
+    v = RNG.uniform(0.0, 3000.0, (S, R, X)).astype(np.float32)
+    want = np.einsum("stx,srx->srt", m.astype(np.float64), v.astype(np.float64))
+    idx = ref.leg_index(jnp.asarray(m))[:, None]  # [S, 1, T]
+    got = np.asarray(ref.gather_legs(jnp.asarray(v), idx))
+    assert got.shape == (S, R, T)
+    assert np.array_equal(got, want.astype(np.float32))
+
+
 def test_grid_tick_ref_broadcasts_batch_dims():
     """The generalized reference accepts stacked operands directly and agrees
     with its own per-scenario evaluation."""

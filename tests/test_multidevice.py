@@ -143,12 +143,16 @@ def test_bank_shards_over_scenario_axis():
         keys = jax.random.split(jax.random.PRNGKey(0), 16).reshape(8, 2, 2)
         ref = simulate_bank(bank, params, keys, leap=True)
 
-        mesh = jax.make_mesh((8,), ("data",))
+        # Auto axes: the compiler partitions the bank from its input
+        # shardings (jax.make_mesh defaults to Explicit axes, which would
+        # type every intermediate's sharding instead)
+        mesh = jax.make_mesh(
+            (8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         shard = lambda a: jax.device_put(
             a, NamedSharding(mesh, P("data", *([None] * (a.ndim - 1)))))
         spec_sh = jax.tree.map(shard, bank_spec(bank))
         params_sh = jax.tree.map(shard, params)
-        with mesh:
+        with jax.set_mesh(mesh):
             out = simulate_bank(spec_sh, params_sh, shard(keys), leap=True)
         for f in ("transfer_time", "conth_mb", "conpr_mb", "done", "ticks"):
             a, b = np.asarray(getattr(ref, f)), np.asarray(getattr(out, f))
