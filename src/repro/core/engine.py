@@ -34,6 +34,7 @@ from repro.core.workload import (
     ScenarioBank,
 )
 from repro.kernels import ops, ref
+from repro.utils import spans
 
 __all__ = [
     "SimSpec",
@@ -131,6 +132,9 @@ class SimResult(NamedTuple):
     ticks: jax.Array  # [] i32 total ticks simulated
     profile: jax.Array  # [T] i32
     start_tick: jax.Array  # [T] f32 first active tick per leg
+    # [] i32 loop steps the simulation took (a tick each, or an event leap
+    # each): set by the banked while loop, None on the other paths
+    steps: Optional[jax.Array] = None
 
 
 class _Carry(NamedTuple):
@@ -493,7 +497,8 @@ def simulate_batch(
 # per-scenario max_ticks scalar and the padding mask
 _BANK_SPEC_AXES = SimSpec(0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0)
 
-_bank_traces = 0
+#: the span table's counter of banked-engine traces (``repro.utils.spans``)
+BANK_TRACES = "engine.bank_traces"
 
 # cache-clear callbacks run by reset_bank_trace_count(clear_caches=True).
 # Higher layers that memoize compiled artifacts keyed on process history
@@ -512,8 +517,9 @@ def register_cache_clear_hook(fn: Callable[[], None]) -> None:
 
 def bank_trace_count() -> int:
     """Number of times the banked engine has been (re)traced in this process
-    — the observable behind the "no per-scenario retrace" contract."""
-    return _bank_traces
+    — the observable behind the "no per-scenario retrace" contract (the
+    ``engine.bank_traces`` counter of :mod:`repro.utils.spans`)."""
+    return spans.snapshot().get(BANK_TRACES, spans.Entry(0, 0.0)).count
 
 
 def reset_bank_trace_count(*, clear_caches: bool = True) -> None:
@@ -528,8 +534,7 @@ def reset_bank_trace_count(*, clear_caches: bool = True) -> None:
     :func:`register_cache_clear_hook`): the order-independent fixture for
     tests and benchmarks.
     """
-    global _bank_traces
-    _bank_traces = 0
+    spans.reset(BANK_TRACES)
     if clear_caches:
         _simulate_bank.clear_cache()
         _simulate_bank_banked.clear_cache()
@@ -549,11 +554,11 @@ class _TraceDelta:
     """Live view of banked-engine traces since the scope was entered."""
 
     def __init__(self) -> None:
-        self._start = _bank_traces
+        self._start = bank_trace_count()
 
     @property
     def count(self) -> int:
-        return _bank_traces - self._start
+        return bank_trace_count() - self._start
 
 
 @contextlib.contextmanager
@@ -681,8 +686,7 @@ def _simulate_bank(
     leap: bool,
     window: int = 1,
 ) -> SimResult:
-    global _bank_traces
-    _bank_traces += 1  # executes at trace time only
+    spans.count(BANK_TRACES)  # executes at trace time only
     return _vmap_bank_core(
         spec, params, keys, backend=backend, leap=leap, window=window
     )
@@ -756,8 +760,10 @@ def _bank_window_body(
     leap: bool,
     window: int,
     c: _Carry,
-) -> _Carry:
-    """Advance the whole bank by one fused ``window``-tick step.
+) -> Tuple[_Carry, jax.Array]:
+    """Advance the whole bank by one fused ``window``-tick step; returns the
+    new carry and each element's ``[S, R]`` count of the loop steps it took
+    in the window (ticks, or event leaps under ``leap``).
 
     One :func:`repro.kernels.ops.grid_tick_bank_fused` dispatch — a single
     kernel launch on the Pallas backend — advances every (scenario, replica)
@@ -782,7 +788,7 @@ def _bank_window_body(
         t=t, remaining=remaining, done=done, started=started,
         t_start=t_start, t_end=t_end, conth=conth, conpr=conpr, bg=bg,
         key=key,
-    )
+    ), steps
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "leap", "window"))
@@ -809,8 +815,7 @@ def _simulate_bank_banked(
     splits follow the per-scenario body exactly — for every ``window``,
     bit-identically to the per-tick loop.
     """
-    global _bank_traces
-    _bank_traces += 1  # executes at trace time only
+    spans.count(BANK_TRACES)  # executes at trace time only
     return _banked_core(
         spec, params, keys, backend=backend, leap=leap, window=window
     )
@@ -829,22 +834,24 @@ def _banked_core(
     entry point and the shard_map per-device body). Under shard_map the loop
     condition is evaluated per device shard — no collectives anywhere in
     cond or body — so a shard whose scenarios all finish early stops
-    dispatching windows while its neighbours keep ticking."""
+    dispatching windows while its neighbours keep ticking. The loop also
+    sums the fused kernel's per-element step counts into ``steps``."""
     init = _banked_init_carry(spec, params, keys)
 
-    def cond(c: _Carry) -> jax.Array:
-        return jnp.any(_banked_live(spec, c))
+    def cond(cs: Tuple[_Carry, jax.Array]) -> jax.Array:
+        return jnp.any(_banked_live(spec, cs[0]))
 
     # every window size runs the same fused body (window=1 is a length-1
     # window): windowed-vs-per-tick parity is then structural — the K-tick
     # and 1-tick programs share one inner step, so XLA's per-expression
     # rounding (FMA contraction in the noise/fair-share math) cannot drift
     # between them the way it does between separately-written loop bodies
-    body = functools.partial(
-        _bank_window_body, spec, params, backend, leap, window
-    )
-    final = jax.lax.while_loop(cond, body, init)
-    return _banked_result(spec, final)
+    def body(cs: Tuple[_Carry, jax.Array]) -> Tuple[_Carry, jax.Array]:
+        c, steps = _bank_window_body(spec, params, backend, leap, window, cs[0])
+        return c, cs[1] + steps
+
+    final, steps = jax.lax.while_loop(cond, body, (init, jnp.zeros_like(init.t)))
+    return _banked_result(spec, final)._replace(steps=steps)
 
 
 # ---------------------------------------------------------------------------
@@ -952,8 +959,7 @@ def _simulate_bank_sharded(
     nothing to verify in a collective-free program (and per-shard
     while-loop trip counts legitimately differ).
     """
-    global _bank_traces
-    _bank_traces += 1  # executes at trace time only
+    spans.count(BANK_TRACES)  # executes at trace time only
 
     n_dev = mesh.devices.size
     axis = mesh.axis_names[0]
@@ -996,9 +1002,8 @@ def _banked_window_step(
     (verified warning-free on CPU; see ``tests/test_tick_window.py``). Do
     not reuse a carry after passing it here.
     """
-    global _bank_traces
-    _bank_traces += 1  # executes at trace time only
-    return _bank_window_body(spec, params, backend, leap, window, carry)
+    spans.count(BANK_TRACES)  # executes at trace time only
+    return _bank_window_body(spec, params, backend, leap, window, carry)[0]
 
 
 @functools.partial(
@@ -1027,8 +1032,7 @@ def _banked_window_step_sharded(
     unsharded step. ``check_vma=False`` for the same reason as the
     monolithic sharded program: there is nothing replicated to verify.
     """
-    global _bank_traces
-    _bank_traces += 1  # executes at trace time only
+    spans.count(BANK_TRACES)  # executes at trace time only
     if carry.t.shape[0] % mesh.devices.size:
         raise ValueError(
             f"sharded window step needs the scenario axis "
@@ -1037,7 +1041,7 @@ def _banked_window_step_sharded(
             "(workload.pad_bank_scenarios)"
         )
     def body(sp: SimSpec, pa: SimParams, ca: _Carry) -> _Carry:
-        return _bank_window_body(sp, pa, backend, leap, window, ca)
+        return _bank_window_body(sp, pa, backend, leap, window, ca)[0]
 
     p = PartitionSpec(mesh.axis_names[0])
     return jax.shard_map(
@@ -1065,8 +1069,7 @@ def _admit_bank_rows(
     1 row costs the same trace as admitting all of them: zero, after the
     first).
     """
-    global _bank_traces
-    _bank_traces += 1  # executes at trace time only
+    spans.count(BANK_TRACES)  # executes at trace time only
     fresh = _banked_init_carry(spec, params, keys)
 
     def merge(new: jax.Array, old: jax.Array) -> jax.Array:
@@ -1099,8 +1102,7 @@ def _admit_bank_rows_sharded(
     carry's sharding, so the admit → step → snapshot cycle holds one stable
     set of jit cache keys under a mesh.
     """
-    global _bank_traces
-    _bank_traces += 1  # executes at trace time only
+    spans.count(BANK_TRACES)  # executes at trace time only
 
     def body(
         sp: SimSpec, pa: SimParams, ke: jax.Array, ca: _Carry, ma: jax.Array
@@ -1141,8 +1143,7 @@ def _bank_snapshot(spec: SimSpec, carry: _Carry):
     (CONTRACTS.md §7), so its result slice is bitwise identical in every
     later version.
     """
-    global _bank_traces
-    _bank_traces += 1  # executes at trace time only
+    spans.count(BANK_TRACES)  # executes at trace time only
     return _bank_snapshot_body(spec, carry)
 
 
@@ -1150,8 +1151,7 @@ def _bank_snapshot(spec: SimSpec, carry: _Carry):
 def _bank_snapshot_sharded(spec: SimSpec, carry: _Carry, *, mesh: Mesh):
     """Sharded twin of :func:`_bank_snapshot` (row-local, collective-free;
     ``check_vma=False`` as for the other sharded bank programs)."""
-    global _bank_traces
-    _bank_traces += 1  # executes at trace time only
+    spans.count(BANK_TRACES)  # executes at trace time only
     p = PartitionSpec(mesh.axis_names[0])
     return jax.shard_map(
         _bank_snapshot_body, mesh=mesh, in_specs=(p, p), out_specs=(p, p),
@@ -1575,6 +1575,8 @@ def _simulate_bank_bucketed_impl(
         ticks=jnp.zeros((n, r), jnp.int32),
         profile=jnp.full((n, r, pad_legs), PAD_PROFILE, jnp.int32),
         start_tick=jnp.zeros((n, r, pad_legs), jnp.float32),
+        # the banked loop counts each element's steps; the vmap program not
+        steps=None if lowering == "vmap" else jnp.zeros((n, r), jnp.int32),
     )
     if not windows:
         windows = (1,) * len(specs)
@@ -1642,6 +1644,7 @@ def _simulate_bank_bucketed_impl(
             ticks=out.ticks.at[ids].set(res.ticks),
             profile=out.profile.at[ids, :, :t_b].set(res.profile),
             start_tick=out.start_tick.at[ids, :, :t_b].set(res.start_tick),
+            steps=None if out.steps is None else out.steps.at[ids].set(res.steps),
         )
     return out
 
@@ -1762,25 +1765,31 @@ def simulate_bank(
     order. Bucketed banks shard each bucket's program over the same mesh,
     keeping the fused windows and the scatter-back device-local per bucket
     (see ``tests/test_multidevice.py``).
+
+    The banked lowering also returns each element's loop steps
+    (``SimResult.steps``, ``[N, R]``); the vmap lowering leaves it ``None``.
+    The host work up to the launch is the ``engine.dispatch`` span
+    (:mod:`repro.utils.spans`).
     """
-    w = _resolve_window(window, leap)
-    mesh = resolve_mesh(mesh)
-    if isinstance(bank, ScenarioBank):
-        # never scan far past the fleet's longest simulation in one window
-        # (pow2-quantized so the static window doesn't retrace on
-        # content-dependent bounds; see _clamp_window)
-        w = _clamp_window(w, int(np.max(np.asarray(bank.max_ticks))))
-    if bucketed and isinstance(bank, BucketedBank):
-        result = _simulate_bank_bucketed(
-            bank, params, keys, backend=backend, leap=leap, lowering=lowering,
-            window=w, mesh=mesh,
-        )
-    else:
-        spec = bank_spec(bank) if isinstance(bank, ScenarioBank) else bank
-        result = _dispatch_bank(
-            spec, params, keys, backend=backend, leap=leap, lowering=lowering,
-            window=w, mesh=mesh,
-        )
+    with spans.span("engine.dispatch"):
+        w = _resolve_window(window, leap)
+        mesh = resolve_mesh(mesh)
+        if isinstance(bank, ScenarioBank):
+            # never scan far past the fleet's longest simulation in one
+            # window (pow2-quantized so the static window doesn't retrace
+            # on content-dependent bounds; see _clamp_window)
+            w = _clamp_window(w, int(np.max(np.asarray(bank.max_ticks))))
+        if bucketed and isinstance(bank, BucketedBank):
+            result = _simulate_bank_bucketed(
+                bank, params, keys, backend=backend, leap=leap,
+                lowering=lowering, window=w, mesh=mesh,
+            )
+        else:
+            spec = bank_spec(bank) if isinstance(bank, ScenarioBank) else bank
+            result = _dispatch_bank(
+                spec, params, keys, backend=backend, leap=leap,
+                lowering=lowering, window=w, mesh=mesh,
+            )
     if _sanitizers_wanted():
         from repro.analysis import sanitize as _sanitize
 

@@ -80,6 +80,7 @@ from repro.core.workload import (
     subset_bank,
     summary_features,
 )
+from repro.utils import spans
 
 if TYPE_CHECKING:
     from repro.core.residency import ResidentBank
@@ -561,36 +562,53 @@ class Fleet:
         in stable scenario order regardless of bucketing. With ``devices``
         (per call or the fleet default) the bank runs as one SPMD program
         sharded over the scenario axis, bit-identical to the unsharded run.
+
+        The host work is timed as the span ``fleet.run``
+        (:mod:`repro.utils.spans`), with ``fleet.params`` (params and
+        replica keys) and ``engine.dispatch`` inside it.
         """
-        params = self._resolve_params(params_or_theta, protocol)
+        with spans.span("fleet.run"):
+            with spans.span("fleet.params"):
+                params = self._resolve_params(params_or_theta, protocol)
+                keys = self._replica_keys(key, keys, replicas)
+            return simulate_bank(
+                self.bank,
+                params,
+                keys,
+                backend=self.backend if backend is None else backend,
+                leap=self.leap if leap is None else leap,
+                lowering=self.lowering if lowering is None else lowering,
+                bucketed=bucketed,
+                window=self.window if window is None else window,
+                mesh=self._resolve_mesh(devices),
+            )
+
+    def _replica_keys(
+        self,
+        key: Optional[jax.Array],
+        keys: Optional[jax.Array],
+        replicas: Optional[int],
+    ) -> jax.Array:
+        """:meth:`run`'s ``[N, R, 2]`` replica keys: split from ``key``, or
+        the explicit ``keys`` checked against the fleet."""
         if keys is None:
             r = 1 if replicas is None else int(replicas)
             key = jax.random.PRNGKey(0) if key is None else key
-            keys = jax.random.split(key, self.n_scenarios * r).reshape(
+            return jax.random.split(key, self.n_scenarios * r).reshape(
                 self.n_scenarios, r, 2
             )
-        elif keys.ndim != 3 or keys.shape[0] != self.n_scenarios:
+        if keys.ndim != 3 or keys.shape[0] != self.n_scenarios:
             # the bucketed scatter would silently clamp a short scenario axis
             raise ValueError(
                 f"keys must be [n_scenarios={self.n_scenarios}, R, 2]: "
                 f"{keys.shape}"
             )
-        elif replicas is not None and keys.shape[1] != replicas:
+        if replicas is not None and keys.shape[1] != replicas:
             raise ValueError(
                 f"explicit keys carry {keys.shape[1]} replicas but "
                 f"replicas={replicas} was requested"
             )
-        return simulate_bank(
-            self.bank,
-            params,
-            keys,
-            backend=self.backend if backend is None else backend,
-            leap=self.leap if leap is None else leap,
-            lowering=self.lowering if lowering is None else lowering,
-            bucketed=bucketed,
-            window=self.window if window is None else window,
-            mesh=self._resolve_mesh(devices),
-        )
+        return keys
 
     def stream(
         self,

@@ -68,6 +68,7 @@ from repro.serve.cache import (
 )
 from repro.serve.request import RequestResult, SimRequest
 from repro.serve.slots import Admission, SlotBank
+from repro.utils import spans
 
 __all__ = ["ServeConfig", "SimServer"]
 
@@ -162,7 +163,9 @@ class SimServer:
         self._unreturned: List[RequestResult] = []
         self.rounds = 0
         self.coalesced = 0
-        # dispatch-vs-sync wall split, accumulated across rounds
+        # the rounds' wall time and its split over the four phases,
+        # accumulated across rounds from the phases' spans
+        self.wall_round_s = 0.0
         self.wall_admit_s = 0.0
         self.wall_dispatch_s = 0.0
         self.wall_sync_s = 0.0
@@ -382,27 +385,42 @@ class SimServer:
 
     def _round(self, now: float) -> bool:
         """One overlapped scheduling round: admit → dispatch → fetch →
-        retire (see the module docstring). Returns True while any bank
-        still holds resident work."""
-        t0 = time.perf_counter()
-        # snapshots dispatched last round — this round's only host sync
-        # reads these, never the steps dispatched below
-        pend = []
-        for bank in self.banks.values():
-            snap = bank.pending_snapshot()
-            if snap is not None:
-                pend.append((bank, snap))
-        self._admit_phase(now)
-        t1 = time.perf_counter()
-        for bank in self.banks.values():
-            if bank.any_believed_live():
-                bank.step(bank.choose_rung())
-        t2 = time.perf_counter()
-        if pend:
-            lives = jax.device_get([snap[1] for _, snap in pend])
-            for (bank, snap), live in zip(pend, lives):
-                bank.apply_snapshot(snap[0], np.asarray(live, bool), snap[2])
-        t3 = time.perf_counter()
+        retire (see the module docstring), each phase a span of
+        :mod:`repro.utils.spans` inside ``serve.round``. Returns True while
+        any bank still holds resident work."""
+        with spans.span("serve.round") as round_:
+            with spans.span("serve.admit") as admit:
+                # snapshots dispatched last round — this round's only host
+                # sync reads these, never the steps dispatched below
+                pend = []
+                for bank in self.banks.values():
+                    snap = bank.pending_snapshot()
+                    if snap is not None:
+                        pend.append((bank, snap))
+                self._admit_phase(now)
+            with spans.span("serve.dispatch") as dispatch:
+                for bank in self.banks.values():
+                    if bank.any_believed_live():
+                        bank.step(bank.choose_rung())
+            with spans.span("serve.sync") as sync:
+                if pend:
+                    lives = jax.device_get([snap[1] for _, snap in pend])
+                    for (bank, snap), live in zip(pend, lives):
+                        bank.apply_snapshot(
+                            snap[0], np.asarray(live, bool), snap[2]
+                        )
+            with spans.span("serve.retire") as retire:
+                self._retire_phase(now)
+        self.wall_round_s += round_.seconds
+        self.wall_admit_s += admit.seconds
+        self.wall_dispatch_s += dispatch.seconds
+        self.wall_sync_s += sync.seconds
+        self.wall_retire_s += retire.seconds
+        return any(b.occupied for b in self.banks.values())
+
+    def _retire_phase(self, now: float) -> None:
+        """Free every slot the fetched snapshots prove finished and store
+        its result."""
         to_retire = [
             (sig, bank, rs)
             for sig, bank in self.banks.items()
@@ -432,12 +450,6 @@ class SimServer:
                 )
                 self.results[done_req.rid] = res
                 self._unreturned.append(res)
-        t4 = time.perf_counter()
-        self.wall_admit_s += t1 - t0
-        self.wall_dispatch_s += t2 - t1
-        self.wall_sync_s += t3 - t2
-        self.wall_retire_s += t4 - t3
-        return any(b.occupied for b in self.banks.values())
 
     def step(self) -> bool:
         """One scheduling round over every slot bank. Returns True while
@@ -512,8 +524,9 @@ class SimServer:
     # -- observability ------------------------------------------------------
 
     def metrics(self) -> dict:
-        """Serving metrics: global counters, the dispatch-vs-sync wall
-        split of the overlapped rounds, and per-signature slot-bank
+        """Serving metrics: global counters, the rounds' wall time and its
+        split over the four phases (the ``serve.*`` spans of this server's
+        rounds), and per-signature slot-bank
         occupancy / rung-histogram / coalesce measurements."""
         return {
             "rounds": self.rounds,
@@ -526,6 +539,7 @@ class SimServer:
             "coalesced": self.coalesced,
             "slots": self.config.slots,
             "replicas": self.config.replicas,
+            "wall_round_s": round(self.wall_round_s, 6),
             "wall_split_s": {
                 "admit": round(self.wall_admit_s, 6),
                 "dispatch": round(self.wall_dispatch_s, 6),
