@@ -158,10 +158,23 @@ BANK_WINDOW_STATE_FIELDS = (
 )
 
 
+def bank_take_legs(v: jax.Array, idx: jax.Array) -> jax.Array:
+    """``v[s, r, idx[s, t]]``: ``[S, R, X] x [S, T] -> [S, R, T]``, the bank
+    scan's gather-direction lookup. The index is the scenario's, shared by
+    all its replica rows, and is never broadcast to ``[S, R, T]`` (see
+    :func:`grid_tick_bank_window`): ``v`` is laid out ``[R, S * X]`` and
+    read by one column gather at the flat indices ``s * X + idx[s, t]``.
+    Callers pass indices in ``[0, X)``."""
+    s, r, x = v.shape
+    flat = jnp.swapaxes(v, 0, 1).reshape(r, s * x)
+    at = (idx + x * jnp.arange(s, dtype=idx.dtype)[:, None]).reshape(-1)
+    out = jnp.take(flat, at, axis=1, mode="clip")  # [R, S * T]
+    return jnp.swapaxes(out.reshape(r, s, -1), 0, 1)
+
+
 def _bank_dep_ok(dep: jax.Array, done: jax.Array) -> jax.Array:
     """``done[s, r, dep[s, t]]`` with -1 mapping to True: [S, R, T]."""
-    idx = jnp.broadcast_to(jnp.maximum(dep, 0)[:, None, :], done.shape)
-    gathered = jnp.take_along_axis(done, idx, axis=2)
+    gathered = bank_take_legs(done, jnp.maximum(dep, 0))
     return jnp.where(dep[:, None, :] >= 0, gathered, True)
 
 
@@ -238,11 +251,16 @@ def grid_tick_bank_window(
     interpret-mode kernel and the TPU kernel share this scan. With
     ``tick=None`` the window runs its built-in fair-share tick. Either way
     the incidence matrices are one-hot, so every gather-direction
-    contraction (process/link quantities back to legs) is a
-    ``take_along_axis`` by the precomputed ``argmax`` index — bit-identical
-    to the one-hot matmul, and cheaper than tiny batched matmuls — and the
-    scatter-direction sums are :func:`onehot_sum`, whose result does not
-    depend on the bank layout or on the backend's matmul precision.
+    contraction (process/link quantities back to legs, and the dependency
+    lookup) is an exact index gather by the precomputed ``argmax`` index,
+    bit-identical to the one-hot matmul with no matmul precision to pin.
+    The index is the scenario's ``[S, T]``, shared by every replica row
+    (:func:`bank_take_legs`): one column gather over the replica rows.
+    Broadcast to ``[S, R, T]``, it made each lookup a general gather of
+    ``S * R * T`` index tuples, which took 97 % of the leap event step's
+    device time on a TPU v5e. The scatter-direction sums are
+    :func:`onehot_sum`, whose result does not depend on the bank layout or
+    on the backend's matmul precision.
     """
     f32 = jnp.float32
     i32 = jnp.int32
@@ -258,13 +276,13 @@ def grid_tick_bank_window(
     # index tables for the gather-direction contractions (process/link
     # quantities back to legs), computed once, outside the scan; the
     # scatter direction is a fixed-order one-hot sum (onehot_sum)
-    proc_of_leg = leg_index(leg_proc)[:, None]  # [S, 1, T]
-    link_of_leg = leg_index(leg_link)[:, None]  # [S, 1, T]
+    proc_of_leg = leg_index(leg_proc)  # [S, T]
+    link_of_leg = leg_index(leg_link)  # [S, T]
     m_cat = jnp.concatenate([leg_proc, leg_link], axis=-1)[:, None]
     n_procs = leg_proc.shape[-1]
 
-    leg_from_proc = lambda v: gather_legs(v, proc_of_leg)
-    leg_from_link = lambda v: gather_legs(v, link_of_leg)
+    leg_from_proc = lambda v: bank_take_legs(v, proc_of_leg)
+    leg_from_link = lambda v: bank_take_legs(v, link_of_leg)
 
     def scatter_pl(v: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """Per-process and per-link sums of a per-leg quantity, as one sum

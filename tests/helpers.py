@@ -3,6 +3,7 @@ optional-``hypothesis`` shim so property-based tests skip (rather than fail at
 collection) when the dependency is absent."""
 from __future__ import annotations
 
+import re
 from typing import List, Tuple
 
 import numpy as np
@@ -120,3 +121,27 @@ def mixed_campaign(seed: int = 0, n_jobs: int = 3, n_accesses: int = 4) -> Tuple
     camp = Campaign(tuple(jobs))
     table = compile_campaign(g, camp)
     return g, camp, table
+
+
+_HLO_DEF = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]")
+_HLO_SHAPE = re.compile(r"^\w+\[([\d,]*)\]")
+
+
+def gather_index_shapes(hlo: str) -> List[Tuple[int, ...]]:
+    """The shape of the index operand of every ``gather`` in an HLO module's
+    text, whether the text prints operand shapes inline (a lowering) or
+    only operand names (a compiled module)."""
+    defs = {}
+    for line in hlo.splitlines():
+        m = _HLO_DEF.match(line)
+        if m:
+            defs[m.group(1)] = m.group(2)
+    shapes = []
+    for line in hlo.splitlines():
+        if " gather(" not in line:
+            continue
+        index = line.split(" gather(", 1)[1].split(", ")[1].split(")")[0]
+        m = _HLO_SHAPE.match(index)
+        dims = m.group(1) if m else defs[index.split()[-1].lstrip("%")]
+        shapes.append(tuple(int(d) for d in dims.split(",") if d))
+    return shapes

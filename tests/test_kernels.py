@@ -1,9 +1,12 @@
 """Per-kernel validation: interpret-mode Pallas vs. pure-jnp oracles over
 shape/dtype sweeps (the CPU-side correctness contract for the TPU kernels)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from helpers import gather_index_shapes
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_pallas
@@ -137,6 +140,75 @@ def test_gather_legs_matches_onehot_matmul():
     got = np.asarray(ref.gather_legs(jnp.asarray(v), idx))
     assert got.shape == (S, R, T)
     assert np.array_equal(got, want.astype(np.float32))
+
+
+def _incidence(S, T, X, pad):
+    """One-hot ``[S, T, X]`` incidences whose last ``pad`` legs are padding
+    (all-zero rows)."""
+    m = np.zeros((S, T, X), np.float32)
+    for s in range(S):
+        m[s, np.arange(T - pad), RNG.randint(0, X, T - pad)] = 1
+    return m
+
+
+@pytest.mark.parametrize("S", [1, 3])
+@pytest.mark.parametrize("R", [1, 2, 64])
+def test_bank_take_legs_matches_onehot_matmul(S, R):
+    """The bank scan's lookup through a replica-shared ``[S, T]`` index
+    equals the float64 one-hot matmul bit for bit on real legs; a padding
+    leg reads entry 0 and is masked by ``active``."""
+    T, X, pad = 23, 7, 4
+    m = _incidence(S, T, X, pad)
+    v = RNG.uniform(0.0, 3000.0, (S, R, X)).astype(np.float32)
+    active = np.broadcast_to(m.any(-1)[:, None, :], (S, R, T))
+    want = np.einsum("stx,srx->srt", m.astype(np.float64), v.astype(np.float64))
+    got = np.asarray(ref.bank_take_legs(jnp.asarray(v), ref.leg_index(jnp.asarray(m))))
+    assert got.shape == (S, R, T)
+    assert np.array_equal(np.where(active, got, 0.0), want.astype(np.float32))
+    assert np.array_equal(got[:, :, T - pad:], np.repeat(v[:, :, :1], pad, axis=2))
+
+
+@pytest.mark.parametrize("S", [1, 3])
+@pytest.mark.parametrize("R", [1, 2, 64])
+def test_bank_dep_ok_matches_onehot_matmul(S, R):
+    """The dependency lookup ``done[s, r, dep[s, t]]`` (``dep = -1``: no
+    dependency, always met) equals the float64 one-hot matmul."""
+    T = 23
+    dep = RNG.randint(-1, T, (S, T)).astype(np.int32)
+    dep[:, :3] = -1
+    done = RNG.rand(S, R, T) < 0.5
+    onehot = (dep[:, :, None] == np.arange(T)).astype(np.float64)  # [S, T, T]
+    via_matmul = np.einsum("stu,sru->srt", onehot, done.astype(np.float64)) > 0.5
+    want = np.where(dep[:, None, :] >= 0, via_matmul, True)
+    got = np.asarray(ref._bank_dep_ok(jnp.asarray(dep), jnp.asarray(done)))
+    assert got.shape == (S, R, T)
+    assert np.array_equal(got, want)
+
+
+def test_bank_window_gathers_share_index_across_replicas():
+    """No gather of the leap window scan takes an index that carries the
+    replica dimension: every lookup reads a ``[S, T]`` index shared by the
+    replica rows, not one broadcast to ``[S, R, T]``."""
+    S, R, T, P, L = 1, 64, 23, 5, 3
+    f32, i32 = jnp.float32, jnp.int32
+    sd = jax.ShapeDtypeStruct
+    state = (
+        sd((S, R), i32), sd((S, R), i32), sd((S, R, T), f32),
+        sd((S, R, T), jnp.bool_), sd((S, R, T), jnp.bool_),
+        sd((S, R, T), i32), sd((S, R, T), i32), sd((S, R, T), f32),
+        sd((S, R, T), f32), sd((S, R, L), f32),
+    )
+    window = jax.jit(functools.partial(ref.grid_tick_bank_window, leap=True))
+    hlo = window.lower(
+        state, sd((S, 1, L), f32), sd((S, 1, L), f32), sd((S, T), i32),
+        sd((S, T), i32), sd((S, L), i32), sd((S,), i32), sd((S, R, T), f32),
+        sd((S, L), f32), sd((S, T, P), f32), sd((S, P, L), f32),
+        sd((S, T, L), f32), noise=sd((4, S, R, L), f32),
+    ).as_text(dialect="hlo")
+    shapes = gather_index_shapes(hlo)
+    # one gather per distinct operand: per-process, per-link, dependency
+    assert len(shapes) == 3, shapes
+    assert all(R not in shape for shape in shapes), shapes
 
 
 def test_grid_tick_ref_broadcasts_batch_dims():

@@ -19,6 +19,7 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
+from helpers import gather_index_shapes
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import classifier, engine
@@ -143,6 +144,27 @@ def test_banked_engine_compiles_at_widest_kernel_pad(sds, leap):
         backend="pallas", leap=leap, window=16 if leap else 32,
     ).compile()
     _assert_kernel(compiled)
+
+
+def test_fleet_leap_gathers_share_index_across_replicas(sds):
+    """The fleet's banked leap program at the production workload's widths,
+    one scenario of 512 replica rows: every gather left in the compiled
+    event step reads an index without the replica dimension (a broadcast
+    ``[S, R, T]`` index lowers to a general gather of ``S * R * T`` index
+    tuples, which took most of the event step's device time)."""
+    _, _, t, p, l = PRODUCTION
+    s, r = 1, 512
+    params = engine.SimParams(
+        keep_frac=sds((s, r, t)), bg_mu=sds((s, r, l)), bg_sigma=sds((s, r, l))
+    )
+    compiled = engine._simulate_bank_banked.lower(
+        _bank_spec(sds, s, t, p, l), params, sds((s, r, 2), jnp.uint32),
+        backend="pallas", leap=True, window=16,
+    ).compile()
+    _assert_kernel(compiled)
+    shapes = gather_index_shapes(compiled.as_text())
+    assert shapes, "no gather left in the event step"
+    assert all(r not in shape for shape in shapes), shapes
 
 
 def test_presimulation_batch_compiles(sds):
