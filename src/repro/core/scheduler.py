@@ -10,7 +10,12 @@ transfer time", with fitness evaluated on GDAPS. This module implements it:
   candidate per access via the engine's ``enabled`` mask.
 - A simple (mu + lambda) evolutionary strategy mutates assignments; fitness is
   the simulated campaign makespan (optionally + mean transfer time), evaluated
-  for the whole population in one ``vmap``-ed batch of simulations.
+  for the whole population in one banked batch of simulations.
+
+:func:`profile_candidates` gives every access of a campaign the paper's
+three realizations; :class:`ProfileSearch` is the search over one
+super-table, its generation program traced once per super-table and search
+settings; :func:`optimize_profiles` runs one search, a generation at a time.
 
 This is the piece that "reduces job wait times": it picks, per job, whichever
 combination of data-placement / stage-in / remote access avoids the currently
@@ -20,14 +25,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import List, NamedTuple, Optional, Sequence, Tuple
+from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import SimParams, SimSpec, simulate
-from repro.core.fleet import Fleet
+from repro.core.fleet import Fleet, _cache_get, _cache_put
 from repro.core.topology import Grid
 from repro.core.workload import (
     AccessProfileKind,
@@ -35,17 +40,29 @@ from repro.core.workload import (
     FileAccess,
     Job,
     LegTable,
+    Replica,
     compile_campaign,
 )
+from repro.utils import spans
 
 __all__ = [
     "CandidateAccess",
     "SuperTable",
+    "profile_candidates",
+    "realized_campaign",
     "build_super_table",
     "super_fleet",
     "evaluate_population",
+    "ProfileSearch",
     "optimize_profiles",
 ]
+
+#: counter of traces of a search's program (counted at trace time only)
+SEARCH_TRACES = "profiles.traces"
+#: the protocols of the paper's three access profiles (Sec. 3 and Sec. 5)
+REMOTE_PROTOCOL = "webdav"
+STAGEIN_PROTOCOL = "xrdcp"
+PLACEMENT_PROTOCOL = "gsiftp"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,6 +71,68 @@ class CandidateAccess:
 
     job: int  # job index within the bag
     candidates: Tuple[FileAccess, ...]
+
+
+def profile_candidates(
+    grid: Grid,
+    campaign: Campaign,
+    local_storage_element: str,
+) -> List[CandidateAccess]:
+    """The paper's three access profiles as candidates of every access of
+    ``campaign``, in job order then access order. Candidate ``k`` of each
+    access reads the same replica, released at the same tick:
+
+    0. remote access from the replica's SE to the job's worker node
+       (:data:`REMOTE_PROTOCOL`; the threads of one job share one stream);
+    1. stage-in over that same link (:data:`STAGEIN_PROTOCOL`, own process);
+    2. placement to ``local_storage_element`` (:data:`PLACEMENT_PROTOCOL`),
+       then stage-in from there to the worker node;
+       an access whose replica already sits there has no placement.
+
+    ``grid`` must hold every link these use."""
+    accesses: List[CandidateAccess] = []
+    for j, job in enumerate(campaign.jobs):
+        wn = job.worker_node
+        for acc in job.accesses:
+            src = acc.replica.storage_element
+            rep = Replica(acc.replica.size_mb, src)
+            t0 = acc.release_tick
+            grid.link(src, wn)  # raises on a missing link
+            cands = [
+                FileAccess(rep, AccessProfileKind.REMOTE, REMOTE_PROTOCOL, t0),
+                FileAccess(rep, AccessProfileKind.STAGE_IN, STAGEIN_PROTOCOL, t0),
+            ]
+            if src != local_storage_element:
+                grid.link(src, local_storage_element)
+                grid.link(local_storage_element, wn)
+                cands.append(FileAccess(
+                    rep, AccessProfileKind.DATA_PLACEMENT, PLACEMENT_PROTOCOL, t0,
+                    local_storage_element=local_storage_element,
+                    stagein_protocol=STAGEIN_PROTOCOL,
+                ))
+            accesses.append(CandidateAccess(job=j, candidates=tuple(cands)))
+    return accesses
+
+
+def realized_campaign(
+    accesses: Sequence[CandidateAccess],
+    worker_nodes: Sequence[str],
+    assign: Sequence[int],
+) -> Campaign:
+    """The ordinary campaign one assignment of a bag stands for: job ``j``
+    on ``worker_nodes[j]`` makes, in order, the chosen candidate of each of
+    its accesses (``assign[i]`` modulo access ``i``'s candidate count, as
+    the super-table reads it). Compiled on the super-table's grid, it
+    simulates as that assignment's member of the super-table does."""
+    n_jobs = max(a.job for a in accesses) + 1
+    chosen: List[List[FileAccess]] = [[] for _ in range(n_jobs)]
+    for acc, k in zip(accesses, np.asarray(assign)):
+        chosen[acc.job].append(acc.candidates[int(k) % len(acc.candidates)])
+    jobs = tuple(
+        Job(worker_node=worker_nodes[j], accesses=tuple(a), name=f"job{j}")
+        for j, a in enumerate(chosen)
+    )
+    return Campaign(jobs, name="realized")
 
 
 class SuperTable(NamedTuple):
@@ -208,6 +287,13 @@ def evaluate_population(
     runs as one :meth:`Fleet.run` dispatch ([1, B, ...]: the masks are
     per-replica params of the single scenario) instead of one ``simulate``
     call per assignment."""
+    return _run_population(st, base_params, pop, keys, makespan_weight,
+                           mean_weight, fleet)[0]
+
+
+def _run_population(st, base_params, pop, keys, makespan_weight, mean_weight, fleet):
+    """:func:`evaluate_population`'s fitness ``[B]`` and the members'
+    simulations (:class:`SimResult` of ``[B, ...]`` fields)."""
     masks = jax.vmap(functools.partial(_assignment_mask, st))(pop)  # [B, T]
     fleet = fleet if fleet is not None else super_fleet(st)
     params = SimParams(
@@ -218,7 +304,125 @@ def evaluate_population(
     )
     res = fleet.run(params, keys=keys[None])
     res = jax.tree.map(lambda a: a[0], res)  # back to [B, ...]
-    return _mask_fitness(res, masks, makespan_weight, mean_weight)
+    return _mask_fitness(res, masks, makespan_weight, mean_weight), res
+
+
+def _search_program(
+    st: SuperTable,
+    fleet: Fleet,
+    population: int,
+    elite: int,
+    mutate_p: float,
+    makespan_weight: float,
+    mean_weight: float,
+    antithetic_sims: int,
+) -> Callable:
+    """The jitted generation program of a search, one per super-table and
+    search settings, kept in the fleet-level compile cache beside the
+    super-table's bank (and dropped with it)."""
+    cache_key = ("profile_search", id(st), population, elite, float(mutate_p),
+                 float(makespan_weight), float(mean_weight), antithetic_sims)
+    hit = _cache_get(cache_key)
+    if hit is not None and hit[0] is st:
+        return hit[1]
+    n_access, n_cand = st.n_access, st.n_cand
+
+    def evaluate(base_params: SimParams, pop: jax.Array, key: jax.Array):
+        keys = jax.random.split(key, antithetic_sims)
+
+        def per_sim(k):
+            ks = jax.random.split(k, pop.shape[0])
+            return _run_population(st, base_params, pop, ks, makespan_weight,
+                                   mean_weight, fleet)
+
+        fit, res = jax.vmap(per_sim)(keys)
+        return jnp.mean(fit, axis=0), res
+
+    def next_gen(pop: jax.Array, fit: jax.Array, key: jax.Array) -> jax.Array:
+        order = jnp.argsort(fit)
+        elites = pop[order[:elite]]
+        k1, k2, k3 = jax.random.split(key, 3)
+        parents = elites[jax.random.randint(k1, (population - elite,), 0, elite)]
+        flip = jax.random.uniform(k2, parents.shape) < mutate_p
+        rand = jax.random.randint(k3, parents.shape, 0, n_cand)
+        children = jnp.where(flip, rand, parents)
+        return jnp.concatenate([elites, children], axis=0)
+
+    legs_per_cand = jnp.asarray((st.cand_legs >= 0).sum(-1), jnp.int32)  # [A, K]
+
+    # repro: allow[jit-cache] -- built once per super-table and settings, then kept in the fleet compile cache below
+    @jax.jit
+    def generation(base_params: SimParams, pop: jax.Array, key: jax.Array):
+        spans.count(SEARCH_TRACES)  # executes at trace time only
+        key, ke, kn = jax.random.split(key, 3)
+        fit, res = evaluate(base_params, pop, ke)
+        chosen = pop % jnp.asarray(st.cands_per_access)
+        enabled = jnp.sum(jnp.take_along_axis(legs_per_cand[None], chosen[..., None], -1))
+        return key, next_gen(pop, fit, kn), fit, enabled, res
+
+    _cache_put(cache_key, (st, generation))
+    return generation
+
+
+class ProfileSearch:
+    """A (mu + lambda) evolutionary search over the candidate assignments of
+    one super-table, under fixed ``base_params`` (the campaign's overheads
+    and background moments; the enabled mask is the search's).
+
+    A generation is one program: the fitness of the whole population as one
+    banked batch on :func:`super_fleet` (``antithetic_sims`` replicas of
+    every member, averaged), then the next population: the ``elite`` best
+    members, and ``population - elite`` children of elites drawn at random,
+    each gene redrawn with probability ``mutate_p``. The program is traced
+    once per super-table and search settings (:data:`SEARCH_TRACES` counts
+    the traces) and takes ``base_params`` as an argument, so a search under
+    other parameters traces nothing.
+
+    Each generation's dispatch and fitness fetch is the span
+    ``profiles.generation``; the counters ``profiles.members`` and
+    ``profiles.enabled_legs`` add up the members evaluated and their enabled
+    legs (:mod:`repro.utils.spans`).
+    """
+
+    def __init__(
+        self,
+        st: SuperTable,
+        base_params: SimParams,
+        *,
+        population: int = 32,
+        elite: int = 8,
+        mutate_p: float = 0.15,
+        makespan_weight: float = 1.0,
+        mean_weight: float = 0.1,
+        antithetic_sims: int = 1,
+    ) -> None:
+        if not 0 < elite <= population:
+            raise ValueError(f"need 0 < elite <= population: {elite}, {population}")
+        self.st = st
+        self.base_params = base_params
+        self.population = population
+        self.fleet = super_fleet(st)
+        self._generation = _search_program(
+            st, self.fleet, population, elite, mutate_p, makespan_weight,
+            mean_weight, antithetic_sims,
+        )
+
+    def generation(self, pop: jax.Array, key: jax.Array):
+        """Evaluate ``pop`` ``[population, n_access]`` and breed the next
+        population, from the chain key ``key``: the simulations' keys and the
+        mutations come from ``jax.random.split(key, 3)[1:]``, and the first
+        of the three is the chain's next key.
+
+        Returns ``(key, next_pop, fitness, result)``: the chain's next key and
+        the next population on the device, the fitness ``[population]`` on the
+        host, and the members' simulations, a :class:`SimResult` of
+        ``[antithetic_sims, population, ...]`` fields on the device."""
+        with spans.span("profiles.generation"):
+            key, next_pop, fit, enabled, res = self._generation(self.base_params, pop, key)
+            fit, enabled = jax.device_get((fit, enabled))
+        spans.count("profiles.members", self.population)
+        spans.count("profiles.enabled_legs", int(enabled))
+        return key, next_pop, fit, res
 
 
 def optimize_profiles(
@@ -232,46 +436,26 @@ def optimize_profiles(
     mutate_p: float = 0.15,
     antithetic_sims: int = 1,
 ) -> Tuple[np.ndarray, float, List[float]]:
-    """(mu + lambda) evolutionary search over candidate assignments.
+    """(mu + lambda) evolutionary search over candidate assignments: one
+    :class:`ProfileSearch`, one generation at a time.
 
     Returns (best assignment [n_access], best fitness, per-generation best).
     """
-    n_access, n_cand = st.n_access, st.n_cand
+    search = ProfileSearch(
+        st, base_params, population=population, elite=elite, mutate_p=mutate_p,
+        antithetic_sims=antithetic_sims,
+    )
     key, k0 = jax.random.split(key)
-    pop = jax.random.randint(k0, (population, n_access), 0, n_cand)
-    fleet = super_fleet(st)  # compiled once, shared by every generation
-
-    # repro: allow[jit-cache] -- intentionally per-call: closes over the compiled super-fleet and is reused across every generation, then dropped with the call
-    @jax.jit
-    def eval_pop(pop: jax.Array, key: jax.Array) -> jax.Array:
-        keys = jax.random.split(key, antithetic_sims)
-        def per_sim(k):
-            ks = jax.random.split(k, pop.shape[0])
-            return evaluate_population(st, base_params, pop, ks, fleet=fleet)
-        return jnp.mean(jax.vmap(per_sim)(keys), axis=0)
-
-    # repro: allow[jit-cache] -- intentionally per-call: closes over the search hyperparameters and is reused across every generation, then dropped with the call
-    @jax.jit
-    def next_gen(pop: jax.Array, fit: jax.Array, key: jax.Array) -> jax.Array:
-        order = jnp.argsort(fit)
-        elites = pop[order[:elite]]
-        k1, k2, k3 = jax.random.split(key, 3)
-        parents = elites[jax.random.randint(k1, (population - elite,), 0, elite)]
-        flip = jax.random.uniform(k2, parents.shape) < mutate_p
-        rand = jax.random.randint(k3, parents.shape, 0, n_cand)
-        children = jnp.where(flip, rand, parents)
-        return jnp.concatenate([elites, children], axis=0)
-
+    pop = jax.random.randint(k0, (population, st.n_access), 0, st.n_cand)
     history: List[float] = []
     best_fit = np.inf
     best_assign = np.asarray(pop[0])
     for g in range(generations):
-        key, ke, kn = jax.random.split(key, 3)
-        fit = eval_pop(pop, ke)
-        i = int(jnp.argmin(fit))
+        key, next_pop, fit, _ = search.generation(pop, key)
+        i = int(np.argmin(fit))
         if float(fit[i]) < best_fit:
             best_fit = float(fit[i])
             best_assign = np.asarray(pop[i])
-        history.append(float(jnp.min(fit)))
-        pop = next_gen(pop, fit, kn)
+        history.append(float(np.min(fit)))
+        pop = next_pop
     return best_assign, best_fit, history

@@ -39,6 +39,8 @@ import jax
 from jax.experimental import pallas as pl
 import jax.numpy as jnp
 
+from repro.kernels.pallas_compat import tpu_compiler_params
+
 __all__ = [
     "grid_tick_pallas",
     "grid_tick_bank_pallas",
@@ -47,6 +49,10 @@ __all__ = [
 
 _LANE = 128
 _SUBLANE = 8
+#: scoped VMEM a Mosaic kernel gets unless it asks for more (TPU v5e), and
+#: the most the fused kernel asks for (the v5e holds 128 MiB)
+_VMEM_SCOPED = 16 * 2**20
+_VMEM_MAX = 96 * 2**20
 
 
 def _dot(a: jax.Array, b: jax.Array, *, exact: bool = False) -> jax.Array:
@@ -465,6 +471,27 @@ def _bank_fused_kernel(
     bg_out[0] = bg
 
 
+def _fused_vmem_bytes(rb: int, Tp: int, Pp: int, Lp: int, K: int,
+                      keep_rows: int, bg_rows: int) -> int:
+    """The fused kernel's VMEM blocks, each double-buffered: one tile's carry
+    in and out, its noise window and moments, and the scenario's constants
+    (the dense incidences and the ``[Tp, Tp]`` dependency block). A block of
+    one row occupies a whole sublane tile."""
+    sub = lambda h: max(h, _SUBLANE)
+    tile = (
+        2 * 2 * rb * _LANE  # clock and steps, in and out
+        + 2 * 7 * rb * Tp  # the seven per-leg carry arrays, in and out
+        + 2 * rb * Lp  # background load, in and out
+        + K * rb * Lp  # noise window
+        + 2 * sub(bg_rows) * Lp + sub(keep_rows) * Tp
+    )
+    scenario = (
+        Tp * Tp + Tp * Pp + Pp * Lp + Tp * Lp
+        + _SUBLANE * (2 * Tp + 2 * Lp + _LANE)  # release, nodep, period, bw, max_ticks
+    )
+    return 4 * 2 * (tile + scenario)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "block_r"))
 def grid_tick_bank_fused_pallas(
     state: Tuple[jax.Array, ...],  # ref.BANK_WINDOW_STATE_FIELDS layout
@@ -496,7 +523,11 @@ def grid_tick_bank_fused_pallas(
     stays MXU/VPU-only.
 
     VMEM budget scales with ``block_r * K`` (the ``noise`` window block);
-    lower ``block_r`` for very large windows.
+    lower ``block_r`` for very large windows. The launch asks for twice
+    the blocks' size (``_fused_vmem_bytes``; the kernel's own scratch takes
+    the rest), at least the scoped default and at most ``_VMEM_MAX``: a
+    bank of 512 replicas of a 424-leg, 329-process campaign needs 17.1 MiB
+    at ``block_r = 128``.
     """
     (t, steps, remaining, done, started, t_start, t_end, conth, conpr,
      bg) = state
@@ -575,6 +606,9 @@ def grid_tick_bank_fused_pallas(
     Rp = remaining_p.shape[1]
     grid = (S, Rp // rb)
 
+    need = _fused_vmem_bytes(rb, Tp, Pp, Lp, K, keep_p.shape[1], mu_p.shape[1])
+    vmem = max(_VMEM_SCOPED, min(2 * need, _VMEM_MAX))
+
     rep_spec = lambda w: pl.BlockSpec((1, rb, w), lambda s, r: (s, r, 0))
     scn_spec = lambda h, w: pl.BlockSpec((1, h, w), lambda s, r: (s, 0, 0))
 
@@ -627,6 +661,7 @@ def grid_tick_bank_fused_pallas(
         ),
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=tpu_compiler_params(vmem_limit_bytes=vmem),
     )(
         t_p, steps_p, remaining_p, done_p, started_p, t_start_p, t_end_p,
         conth_p, conpr_p, bg_p, noise_p, mu_p, sigma_p, release_p, mdep_p,

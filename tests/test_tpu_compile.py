@@ -33,6 +33,9 @@ F32, I32 = jnp.float32, jnp.int32
 # and the paper's production workload at calibration batch width
 BENCH = (64, 4, 58, 58, 11)
 PRODUCTION = (1, 4096, 106, 11, 1)
+# the access-profile search over the production campaign: its super-table of
+# three candidates an access, 512 members as the replica rows
+SEARCH = (1, 512, 424, 329, 3)
 
 
 @pytest.fixture(scope="module")
@@ -97,17 +100,23 @@ def _compile_fused(sds, s, r, t, p, l, k=32):  # k: the TPU tick window
     ).compile()
 
 
-@pytest.mark.parametrize("width", [BENCH, PRODUCTION], ids=["bench", "production"])
+@pytest.mark.parametrize("width", [BENCH, PRODUCTION, SEARCH],
+                         ids=["bench", "production", "search"])
 def test_fused_window_kernel_compiles(sds, width):
+    """At the search's widths (pads 512 legs, 384 processes, 128 links) the
+    kernel's blocks take 17.1 MiB at 128 rows a tile, over the 16 MiB of
+    scoped VMEM: the launch asks for more."""
     _assert_kernel(_compile_fused(sds, *width))
 
 
-@pytest.mark.parametrize("legs, fits", [(640, True), (768, False)])
+@pytest.mark.parametrize("legs, fits", [(768, True), (1792, True), (1920, False)])
 def test_fused_window_kernel_vmem_limit(sds, legs, fits):
     """The fused kernel keeps a scenario's dense incidences in VMEM (the
-    ``[T, T]`` dependency block among them), so with one process per leg
-    it fits up to 640 padded legs and 768 is the first width that runs out
-    (ROADMAP R1). Legs-axis tiling should turn the second case around."""
+    ``[T, T]`` dependency block among them). Its launch asks for twice its
+    blocks' size, at most 96 MiB, so with one process per leg it fits up to
+    1792 padded legs and 1920 is the first width that runs out (ROADMAP
+    R1; at the 16 MiB default 768 ran out). Legs-axis tiling should turn
+    the last case around."""
     if fits:
         _assert_kernel(_compile_fused(sds, 1, 4, legs, legs, 8))
     else:
@@ -142,6 +151,22 @@ def test_banked_engine_compiles_at_widest_kernel_pad(sds, leap):
     compiled = engine._simulate_bank_banked.lower(
         _bank_spec(sds, s, t, p, l), params, sds((s, r, 2), jnp.uint32),
         backend="pallas", leap=leap, window=16 if leap else 32,
+    ).compile()
+    _assert_kernel(compiled)
+
+
+def test_search_generation_compiles(sds):
+    """The search's simulation at the cell's shape: the banked tick program
+    with one ``enabled`` mask per member, the dependency path of the
+    placements in the fused kernel."""
+    s, r, t, p, l = SEARCH
+    params = engine.SimParams(
+        keep_frac=sds((s, t)), bg_mu=sds((s, l)), bg_sigma=sds((s, l)),
+        enabled=sds((s, r, t), jnp.bool_),
+    )
+    compiled = engine._simulate_bank_banked.lower(
+        _bank_spec(sds, s, t, p, l), params, sds((s, r, 2), jnp.uint32),
+        backend="pallas", leap=False, window=32,
     ).compile()
     _assert_kernel(compiled)
 
