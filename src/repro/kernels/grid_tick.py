@@ -11,7 +11,9 @@ round-trips between the fused stages.
 Three kernels share that layout:
 
 - ``grid_tick_pallas`` — one tick, one campaign's incidences broadcast to
-  every batch block;
+  every batch block (``ops.grid_tick`` runs it on one simulation's state;
+  under ``vmap`` it folds the mapped simulations into the rows of
+  ``grid_tick_bank_pallas`` instead);
 - ``grid_tick_bank_pallas`` — one tick of a **scenario bank** (per-scenario
   incidence operands, grid over ``(scenario, replica-block)``);
 - ``grid_tick_bank_fused_pallas`` — ``K`` ticks of a scenario bank in one

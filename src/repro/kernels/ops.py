@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
+from repro.utils import spans
 
 __all__ = [
     "default_backend",
@@ -68,19 +69,104 @@ def grid_tick(
     *,
     backend: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One fair-share tick of one campaign: per-sim state ``active`` /
+    ``remaining`` ``[T]`` (or rows ``[..., T]``), ``bg_load`` ``[L]`` (or
+    ``[..., L]``), ``keep_frac`` ``[T]`` (or per row), against the campaign's
+    ``bandwidth [L]`` and incidences.
+
+    On the Pallas backends a ``vmap`` of this call folds its mapped axis into
+    the kernel's rows (256 a grid step) instead of one grid step per mapped
+    simulation; see :func:`_pallas_tick`."""
     b = _resolve(backend)
     if b == "xla":
         return ref.grid_tick(
             active, remaining, keep_frac, bg_load, bandwidth,
             leg_proc, proc_link, leg_link,
         )
-    from repro.kernels import grid_tick as _k
-
-    return _k.grid_tick_pallas(
+    return _pallas_tick(b == "pallas_interpret")(
         active, remaining, keep_frac, bg_load, bandwidth,
         leg_proc, proc_link, leg_link,
-        interpret=(b == "pallas_interpret"),
     )
+
+
+#: counters of :mod:`repro.utils.spans`: traces of the per-sim tick's
+#: batching rule that folded the mapped axis into the kernel's rows, and
+#: those that made it the bank kernel's scenario axis (mapped incidences)
+TICK_FOLDED = "kernels.grid_tick.folded"
+TICK_UNFOLDED = "kernels.grid_tick.unfolded"
+
+
+@functools.lru_cache(maxsize=None)
+def _pallas_tick(interpret: bool):
+    """The Pallas fair-share tick of :func:`grid_tick`, with a batching rule.
+
+    Called as is, ``[T]`` state runs ``grid_tick_pallas`` and rows
+    ``[..., T]`` run ``grid_tick_bank_pallas`` as one scenario of
+    ``prod(...)`` replica rows. Under ``vmap`` Pallas's own rule would add a
+    grid axis of the batch size, one 8-row block (one real row) a step. The
+    rule here instead:
+
+    - folds the mapped axis into the rows when only per-row operands
+      (``active``, ``remaining``, ``bg_load``, ``keep_frac``) are mapped, by
+      calling this same function on the stacked rows, so that an enclosing
+      ``vmap`` folds its axis in turn;
+    - makes the mapped axis the bank kernel's scenario axis when the
+      campaign operands are mapped too (the vmap lowering of
+      ``simulate_bank``, per-scenario incidences), rows as replicas.
+
+    Each row's output depends on that row alone, so every path gives the
+    same bits per row.
+    """
+    from repro.kernels import grid_tick as _k
+
+    def bank(active, remaining, keep, bg, bw, m_tp, m_pl, m_tl):
+        """Bank kernel over ``[S, *rows, ...]`` state, ``keep`` ``[S, T]``
+        (shared by the rows) or ``[S, *rows, T]``."""
+        s, rows = active.shape[0], active.shape[1:-1]
+        flat = lambda x: x.reshape(s, -1, x.shape[-1])
+        keep = keep if keep.ndim == 2 else flat(keep)
+        outs = _k.grid_tick_bank_pallas(
+            flat(active), flat(remaining), keep, flat(bg), bw, m_tp, m_pl, m_tl,
+            interpret=interpret,
+        )
+        return tuple(o.reshape((s, *rows, o.shape[-1])) for o in outs)
+
+    @jax.custom_batching.custom_vmap
+    def tick(active, remaining, keep, bg, bw, m_tp, m_pl, m_tl):
+        if active.ndim == 1:
+            return _k.grid_tick_pallas(
+                active, remaining, keep, bg, bw, m_tp, m_pl, m_tl,
+                interpret=interpret,
+            )
+        outs = bank(active[None], remaining[None], keep[None], bg[None],
+                    bw[None], m_tp[None], m_pl[None], m_tl[None])
+        return tuple(o[0] for o in outs)
+
+    @tick.def_vmap
+    def rule(axis_size, in_batched, active, remaining, keep, bg, *campaign):
+        def lift(x, batched):
+            return x if batched else jnp.broadcast_to(x, (axis_size, *x.shape))
+
+        a_b, r_b, keep_b, bg_b = in_batched[:4]
+        active, remaining, bg = lift(active, a_b), lift(remaining, r_b), lift(bg, bg_b)
+        if any(in_batched[4:]):
+            spans.count(TICK_UNFOLDED)
+            campaign = [lift(x, b) for x, b in zip(campaign, in_batched[4:])]
+            out = bank(active, remaining, lift(keep, keep_b), bg, *campaign)
+            return out, (True,) * 3
+        spans.count(TICK_FOLDED)
+        if keep_b or keep.ndim > 1:
+            # per row from here on: a keep mapped by this axis alone repeats
+            # over the call's own rows
+            keep = lift(keep, keep_b)
+            keep = jnp.broadcast_to(
+                keep.reshape(keep.shape[:1] + (1,) * (active.ndim - keep.ndim)
+                             + keep.shape[1:]),
+                active.shape,
+            )
+        return tick(active, remaining, keep, bg, *campaign), (True,) * 3
+
+    return tick
 
 
 def grid_tick_bank(

@@ -145,3 +145,21 @@ def gather_index_shapes(hlo: str) -> List[Tuple[int, ...]]:
         dims = m.group(1) if m else defs[index.split()[-1].lstrip("%")]
         shapes.append(tuple(int(d) for d in dims.split(",") if d))
     return shapes
+
+
+def pallas_grids(jaxpr) -> List[Tuple[int, ...]]:
+    """The grid of every ``pallas_call`` in a jaxpr, nested jaxprs (jit,
+    loops, custom rules) included, in the order they appear."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                if isinstance(sub, ClosedJaxpr):
+                    grids += pallas_grids(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    grids += pallas_grids(sub)
+    return grids

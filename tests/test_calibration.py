@@ -233,6 +233,45 @@ def test_presimulate_bank_scenario_major_layout_and_bucket_parity():
     )
 
 
+@pytest.mark.parametrize("n_replicates", [1, 2])
+@pytest.mark.parametrize("leap", [True, False])
+def test_presim_chunk_body_folds_the_tick_into_kernel_rows(leap, n_replicates):
+    """``presimulate``'s chunk body, ``vmap`` of ``simulate_coefficients``
+    on the Pallas tick (interpret mode): every ``vmap`` folds its axis into
+    the kernel's rows (the ``kernels.grid_tick.folded`` counter, none made a
+    grid axis) and each theta's coefficients equal, bit for bit, its own
+    unbatched call's."""
+    from repro.kernels import ops
+    from repro.utils import spans
+
+    grid, camp = wlcg_production_workload(
+        n_waves=3, wave_period_ticks=60, n_observations=12, max_size_mb=600.0,
+        link_bandwidth=250.0, bg_update_period=20, seed=0,
+    )
+    table = compile_campaign(grid, camp)
+    spec = SimSpec.from_table(table, max_ticks=5_000)
+    mapper = make_theta_mapper(table, "webdav")
+    b = 5
+    thetas = PriorBox.paper().from_unit(jax.random.uniform(jax.random.PRNGKey(1), (b, 3)))
+    keys = jax.random.split(jax.random.PRNGKey(2), b)
+
+    def coefs(theta, key):
+        return simulate_coefficients(
+            spec, mapper(theta), key, backend="pallas_interpret",
+            n_replicates=n_replicates, leap=leap,
+        )
+
+    count = lambda snap, name: snap.get(name, spans.Entry(0, 0.0)).count
+    before = spans.snapshot()
+    chunk = np.asarray(jax.jit(jax.vmap(coefs))(thetas, keys))
+    after = spans.snapshot()
+    assert count(after, ops.TICK_FOLDED) > count(before, ops.TICK_FOLDED)
+    assert count(after, ops.TICK_UNFOLDED) == count(before, ops.TICK_UNFOLDED)
+    one = jax.jit(coefs)
+    for i in range(b):
+        np.testing.assert_array_equal(chunk[i], np.asarray(one(thetas[i], keys[i])))
+
+
 def test_gelman_rubin_detects_mixing():
     """R-hat ~1 for well-mixed chains, >>1 for disjoint chains."""
     rng = np.random.RandomState(0)

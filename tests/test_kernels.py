@@ -6,14 +6,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from helpers import gather_index_shapes
+from helpers import gather_index_shapes, pallas_grids
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import _bwd_chunked, flash_attention_pallas
 from repro.kernels.grid_tick import grid_tick_bank_pallas, grid_tick_pallas
 from repro.kernels.mlstm_chunk import mlstm_chunk_pallas
 from repro.kernels.selu_mlp import selu_mlp_pallas
+from repro.utils import spans
 
 RNG = np.random.RandomState(0)
 
@@ -102,6 +103,116 @@ def test_grid_tick_bank_matches_oracle(S, R, T, P, L):
     for r, p in zip(o_ref, o_pal):
         np.testing.assert_allclose(np.asarray(r), np.asarray(p),
                                    rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# grid_tick under vmap: the batching rule folds mapped sims into kernel rows
+# ---------------------------------------------------------------------------
+# per case, the in_axes of the outer vmap and of the inner one (None: no
+# inner vmap) over active, remaining, keep, bg, bandwidth and the incidences
+_ROW = (0, 0, None, 0) + (None,) * 4
+_VMAPS = {
+    "keep_mapped": ((0, 0, 0, 0) + (None,) * 4, None),
+    "keep_shared": (_ROW, None),
+    "leap": ((0, None, 0, 0) + (None,) * 4, None),  # the leap step: remaining unmapped
+    "nested": ((0, 0, 0, 0) + (None,) * 4, _ROW),  # per-theta keep, replicas inside
+    "incidences_mapped": ((0,) * 8, _ROW),  # the vmap lowering of simulate_bank
+}
+# (case, mapped shape): B not a multiple of 8 or of 256, nested vmaps, and
+# per-scenario incidences (the fallback)
+_VMAP_CASES = [
+    ("keep_mapped", (300,)),
+    ("keep_shared", (13,)),
+    ("leap", (37,)),
+    ("nested", (3, 5)),
+    ("incidences_mapped", (3, 4)),
+]
+
+
+def _vmapped(tick, case):
+    outer, inner = _VMAPS[case]
+    return jax.vmap(tick if inner is None else jax.vmap(tick, in_axes=inner),
+                    in_axes=outer)
+
+
+def _n_mapped(case):
+    """How many of the case's vmaps map each operand."""
+    outer, inner = _VMAPS[case]
+    return [(o is not None) + (i is not None)
+            for o, i in zip(outer, inner or (None,) * 8)]
+
+
+def _case_args(case, shape, T=106, P=11, L=2):
+    """Operands of a random campaign, each with as many leading axes as the
+    case's vmaps map it over."""
+    n_active, n_rem, n_keep, n_bg, n_camp = _n_mapped(case)[:5]
+    lead = shape[:n_camp]
+    m_tp = np.zeros(lead + (T, P), np.float32)
+    m_pl = np.zeros(lead + (P, L), np.float32)
+    for s in np.ndindex(*lead):
+        m_tp[s][np.arange(T), RNG.randint(0, P, T)] = 1
+        m_pl[s][np.arange(P), RNG.randint(0, L, P)] = 1
+    arrays = [
+        RNG.rand(*shape[:n_active], T) < 0.5,
+        RNG.uniform(0.01, 50, shape[:n_rem] + (T,)),
+        RNG.uniform(0.8, 1, shape[:n_keep] + (T,)),
+        RNG.uniform(-1, 5, shape[:n_bg] + (L,)),
+        RNG.uniform(10, 100, lead + (L,)),
+        m_tp, m_pl, m_tp @ m_pl,
+    ]
+    return [jnp.asarray(a, jnp.float32) for a in arrays]
+
+
+@pytest.mark.parametrize("case,shape", _VMAP_CASES)
+def test_vmapped_grid_tick_matches_row_by_row_calls(case, shape):
+    """A vmapped ``ops.grid_tick`` (interpret mode) gives, bit for bit, the
+    row-by-row unbatched calls and today's Pallas batching (a grid step per
+    mapped sim), and the reference at the oracle test's tolerance (its
+    one-hot sums add in another order)."""
+    args = _case_args(case, shape)
+    tick = functools.partial(ops.grid_tick, backend="pallas_interpret")
+    before = spans.snapshot()
+    out = jax.jit(_vmapped(tick, case))(*args)
+    after = spans.snapshot()
+    folded, unfolded = (
+        after.get(n, spans.Entry(0, 0.0)).count - before.get(n, spans.Entry(0, 0.0)).count
+        for n in (ops.TICK_FOLDED, ops.TICK_UNFOLDED)
+    )
+    if case == "incidences_mapped":
+        assert (folded, unfolded) == (1, 1)  # replicas fold, scenarios do not
+    else:
+        assert (folded, unfolded) == (len(shape), 0)
+
+    one = jax.jit(tick)
+    n_mapped = _n_mapped(case)
+    for idx in np.ndindex(*shape):
+        row = one(*(a[idx[:n]] for a, n in zip(args, n_mapped)))
+        for o, r in zip(out, row):
+            np.testing.assert_array_equal(np.asarray(o)[idx], np.asarray(r))
+    pallas = functools.partial(grid_tick_pallas, interpret=True)
+    today = jax.jit(_vmapped(pallas, case))(*args)
+    oracle = _vmapped(ref.grid_tick, case)(*args)
+    for o, t, r in zip(out, today, oracle):
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(t))
+        np.testing.assert_allclose(np.asarray(o), np.asarray(r), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case,shape", _VMAP_CASES + [("keep_mapped", (1000,))])
+def test_vmapped_grid_tick_grid_has_row_blocks(case, shape):
+    """The mechanism: the folded call is one kernel whose grid holds
+    ``ceil(rows / 256)`` row blocks, not one step per mapped sim; mapped
+    incidences make the outer axis the bank kernel's scenario axis."""
+    args = _case_args(case, shape)
+    tick = functools.partial(ops.grid_tick, backend="pallas_interpret")
+    grids = pallas_grids(jax.make_jaxpr(_vmapped(tick, case))(*args).jaxpr)
+    if case == "incidences_mapped":
+        assert grids == [(shape[0], -(-shape[1] // 256))]
+    else:
+        assert grids == [(1, -(-int(np.prod(shape)) // 256))]
+    # what Pallas's own batching rule gives the same call
+    pallas = functools.partial(grid_tick_pallas, interpret=True)
+    today = pallas_grids(jax.make_jaxpr(_vmapped(pallas, case))(*args).jaxpr)
+    assert today == [tuple(shape) + (1,)]
 
 
 @pytest.mark.parametrize("T,X", [(1, 3), (37, 5), (58, 19), (106, 11)])

@@ -194,7 +194,8 @@ def test_fleet_leap_gathers_share_index_across_replicas(sds):
 
 def test_presimulation_batch_compiles(sds):
     """``calibration.presimulate``'s program: the per-campaign leap engine
-    vmapped over a batch of theta draws, one ``grid_tick_pallas`` per event."""
+    vmapped over a batch of theta draws, one fair-share tick kernel per event
+    with the batch folded into its rows (``ops.grid_tick``'s batching rule)."""
     _, _, t, p, l = PRODUCTION
     b = 512
     spec = engine.SimSpec(
